@@ -60,8 +60,8 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
-import sys
+
+from benchmarks.common import child_json
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
@@ -336,36 +336,15 @@ print(json.dumps(out))
 
 
 def _bench(code: str, devices: int = 0, smoke: bool = False) -> dict:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={devices}" if devices
-        else ""  # single real CPU device
-    )
-    if smoke:
-        env["REPRO_BENCH_SMOKE"] = "1"
-    else:
-        env.pop("REPRO_BENCH_SMOKE", None)
-    env["PYTHONPATH"] = (
-        os.path.join(REPO, "src") + os.pathsep + env.get("PYTHONPATH", "")
-    )
-    proc = subprocess.run(
-        [sys.executable, "-c", code],
-        capture_output=True, text=True, timeout=1800, env=env, cwd=REPO,
-    )
-    if proc.returncode != 0:
-        print(f"# comm_step bench failed:\n{proc.stderr}", file=sys.stderr)
-        return {}
-    return json.loads(proc.stdout.strip().splitlines()[-1])
+    return child_json(code, what="comm_step bench", devices=devices,
+                      smoke=smoke)
 
 
 def run(paper_scale: bool = False, smoke: bool = False):
     del paper_scale
     art = _bench(_CODE, smoke=smoke)
-    if not art:
-        return []
-    meshed = _bench(_MESHED_CODE, devices=2 if smoke else 8, smoke=smoke)
-    if meshed:
-        art["meshed"] = meshed
+    art["meshed"] = _bench(_MESHED_CODE, devices=2 if smoke else 8,
+                           smoke=smoke)
     if not smoke:  # smoke runs must not clobber the measured artifact
         with open(ARTIFACT, "w") as f:
             json.dump(art, f, indent=1)
@@ -395,7 +374,7 @@ def run(paper_scale: bool = False, smoke: bool = False):
                 "us_per_call": round(r["speedup_ws_vs_prior"], 3),
                 "derived": "vs PR1 _leaf_aggregate (no-regression check)",
             })
-    for r in meshed.get("rows", []):
+    for r in art["meshed"].get("rows", []):
         tag = f"comm_step_meshed/n{r['n']}/{r['uplink']}"
         derived = f"mesh={r['mesh']},c={r['c']},s={r['s']}"
         for k in ("dense", "ws", "shard"):

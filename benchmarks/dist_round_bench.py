@@ -25,9 +25,9 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
-import subprocess
-import sys
 import time
+
+from benchmarks.common import child_json
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
@@ -131,19 +131,8 @@ print(json.dumps(rows))
 
 
 def _bench_dist_uplink():
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = (
-        os.path.join(REPO, "src") + os.pathsep + env.get("PYTHONPATH", "")
-    )
-    proc = subprocess.run(
-        [sys.executable, "-c", _DIST_CODE],
-        capture_output=True, text=True, timeout=900, env=env, cwd=REPO,
-    )
-    if proc.returncode != 0:
-        print(f"# dist_uplink bench failed:\n{proc.stderr}", file=sys.stderr)
-        return []
-    return json.loads(proc.stdout.strip().splitlines()[-1])
+    return child_json(_DIST_CODE, what="dist_uplink bench", devices=8,
+                      timeout=900)
 
 
 def run(paper_scale: bool = False):

@@ -37,9 +37,9 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
-import sys
 import tempfile
+
+from benchmarks.common import child_json, run_cpu_child
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
@@ -182,43 +182,17 @@ def _ensure_latency_dist(smoke: bool) -> str:
     else:
         path = LATENCY_DIST
         rounds = 12
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = ""
-    env["PYTHONPATH"] = (
-        os.path.join(REPO, "src") + os.pathsep + env.get("PYTHONPATH", "")
-    )
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "examples",
-                                      "availability_sim.py"),
+    run_cpu_child(
+        [os.path.join(REPO, "examples", "availability_sim.py"),
          "--dist", "--rounds", str(rounds), "--dist-out", path],
-        capture_output=True, text=True, timeout=1800, env=env, cwd=REPO,
+        what="latency-dist export",
     )
-    if proc.returncode != 0:
-        print(f"# latency-dist export failed:\n{proc.stderr}",
-              file=sys.stderr)
-        return ""
     return path
 
 
 def _bench(smoke: bool, dist_path: str) -> dict:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = ""  # single real CPU device
-    env["REPRO_LATENCY_DIST"] = dist_path
-    if smoke:
-        env["REPRO_BENCH_SMOKE"] = "1"
-    else:
-        env.pop("REPRO_BENCH_SMOKE", None)
-    env["PYTHONPATH"] = (
-        os.path.join(REPO, "src") + os.pathsep + env.get("PYTHONPATH", "")
-    )
-    proc = subprocess.run(
-        [sys.executable, "-c", _CODE],
-        capture_output=True, text=True, timeout=1800, env=env, cwd=REPO,
-    )
-    if proc.returncode != 0:
-        print(f"# pipeline bench failed:\n{proc.stderr}", file=sys.stderr)
-        return {}
-    return json.loads(proc.stdout.strip().splitlines()[-1])
+    return child_json(_CODE, what="pipeline bench", smoke=smoke,
+                      env_extra={"REPRO_LATENCY_DIST": dist_path})
 
 
 def run(paper_scale: bool = False, smoke: bool = False,
@@ -228,11 +202,7 @@ def run(paper_scale: bool = False, smoke: bool = False,
     it so the clock is always priced at the current measured tail."""
     del paper_scale
     dist_path = latency_dist or _ensure_latency_dist(smoke=smoke)
-    if not dist_path:
-        return []
     art = _bench(smoke=smoke, dist_path=dist_path)
-    if not art:
-        return []
     if not smoke:  # smoke runs must not clobber the measured artifact
         with open(ARTIFACT, "w") as f:
             json.dump(art, f, indent=1)

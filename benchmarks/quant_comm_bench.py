@@ -35,8 +35,8 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
-import sys
+
+from benchmarks.common import child_json
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
@@ -261,35 +261,14 @@ print(json.dumps(out))
 
 
 def _bench(code: str, devices: int = 0, smoke: bool = False) -> dict:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={devices}" if devices
-        else ""  # single real CPU device
-    )
-    if smoke:
-        env["REPRO_BENCH_SMOKE"] = "1"
-    else:
-        env.pop("REPRO_BENCH_SMOKE", None)
-    env["PYTHONPATH"] = (
-        os.path.join(REPO, "src") + os.pathsep + env.get("PYTHONPATH", "")
-    )
-    proc = subprocess.run(
-        [sys.executable, "-c", code],
-        capture_output=True, text=True, timeout=1800, env=env, cwd=REPO,
-    )
-    if proc.returncode != 0:
-        print(f"# quant_comm bench failed:\n{proc.stderr}",
-              file=sys.stderr)
-        return {}
-    return json.loads(proc.stdout.strip().splitlines()[-1])
+    return child_json(code, what="quant_comm bench", devices=devices,
+                      smoke=smoke)
 
 
 def run(paper_scale: bool = False, smoke: bool = False):
     del paper_scale
     meshed = _bench(_MESHED_CODE, devices=2 if smoke else 8, smoke=smoke)
     conv = _bench(_CONV_CODE, smoke=smoke)
-    if not meshed or not conv:
-        return []
     art = {
         "meshed": meshed,
         "convergence": conv,

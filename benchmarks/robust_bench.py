@@ -62,8 +62,8 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
-import sys
+
+from benchmarks.common import child_json
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
@@ -395,30 +395,12 @@ print(json.dumps(out))
 
 
 def _bench(smoke: bool) -> dict:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = ""  # single real CPU device
-    if smoke:
-        env["REPRO_BENCH_SMOKE"] = "1"
-    else:
-        env.pop("REPRO_BENCH_SMOKE", None)
-    env["PYTHONPATH"] = (
-        os.path.join(REPO, "src") + os.pathsep + env.get("PYTHONPATH", "")
-    )
-    proc = subprocess.run(
-        [sys.executable, "-c", _CODE],
-        capture_output=True, text=True, timeout=1800, env=env, cwd=REPO,
-    )
-    if proc.returncode != 0:
-        print(f"# robust bench failed:\n{proc.stderr}", file=sys.stderr)
-        return {}
-    return json.loads(proc.stdout.strip().splitlines()[-1])
+    return child_json(_CODE, what="robust bench", smoke=smoke)
 
 
 def run(paper_scale: bool = False, smoke: bool = False):
     del paper_scale
     art = _bench(smoke=smoke)
-    if not art:
-        return []
     if not smoke:  # smoke runs must not clobber the measured artifact
         with open(ARTIFACT, "w") as f:
             json.dump(art, f, indent=1)

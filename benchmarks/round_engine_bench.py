@@ -25,8 +25,8 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
-import sys
+
+from benchmarks.common import child_json
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
@@ -143,27 +143,12 @@ print(json.dumps(out))
 
 
 def _bench() -> dict:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = (
-        os.path.join(REPO, "src") + os.pathsep + env.get("PYTHONPATH", "")
-    )
-    proc = subprocess.run(
-        [sys.executable, "-c", _CODE],
-        capture_output=True, text=True, timeout=1800, env=env, cwd=REPO,
-    )
-    if proc.returncode != 0:
-        print(f"# round_engine bench failed:\n{proc.stderr}",
-              file=sys.stderr)
-        return {}
-    return json.loads(proc.stdout.strip().splitlines()[-1])
+    return child_json(_CODE, what="round_engine bench", devices=8)
 
 
 def run(paper_scale: bool = False):
     del paper_scale
     art = _bench()
-    if not art:
-        return []
     with open(ARTIFACT, "w") as f:
         json.dump(art, f, indent=1)
     cfg = art["config"]

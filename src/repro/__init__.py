@@ -13,5 +13,3 @@ Subpackages:
 """
 
 __version__ = "1.0.0"
-
-from repro import _compat as _compat  # installs jax forward-compat shims

@@ -92,6 +92,8 @@ def restore(path: str, like: Params, shardings: Optional[Params] = None
     A leaf-count mismatch names the offending leaf *paths* (saved names
     vs the names of ``like``), not just the counts — the error you get
     when restoring into a state whose structure drifted across versions.
+    Leaves that are numpy arrays or scalars in ``like`` come back as
+    numpy arrays of their dtype; every other leaf as a jax array.
     """
     with np.load(os.path.join(path, "arrays.npz")) as z:
         arrays = [z[f"leaf_{i}"] for i in range(len(z.files))]
@@ -120,7 +122,12 @@ def restore(path: str, like: Params, shardings: Optional[Params] = None
             import ml_dtypes
 
             arr = arr.view(ml_dtypes.bfloat16)  # bit-exact restore
-        out.append(jnp.asarray(arr, dtype=ref.dtype))
+        if isinstance(ref, (np.ndarray, np.generic)):
+            # host leaves stay host arrays: float64 survives, where a
+            # jnp array would narrow it to float32 outside x64 mode
+            out.append(np.asarray(arr, dtype=ref.dtype))
+        else:
+            out.append(jnp.asarray(arr, dtype=ref.dtype))
     tree = jax.tree.unflatten(jax.tree.structure(like), out)
     if shardings is not None:
         tree = jax.device_put(tree, shardings)

@@ -685,32 +685,24 @@ def _pallas_comm(xw, hw, slot, band, m: int, s: int, scale, block: int,
     from repro.kernels import compress as _compress
     from repro.kernels import uplink  # lazy: keep dist importable w/o pallas
 
-    def _msum(counts):
-        # wire lanes: int codes dequantize in-tile against their chunk
-        # scales; narrow float lanes cast per tile — either way the
-        # accumulation (and the psum shape upstream) stays f32
-        if wire_scales is not None:
-            return uplink.masked_sum_dequant(
-                wire_x, wire_scales, wire_chunk, slot, band, m, s,
-                counts=counts, block=block,
-            )
+    # wire lanes: int codes expand through the shared dequant before the
+    # kernels (robust stats run on DEQUANTIZED values, the §13 rule);
+    # narrow float lanes cast per tile — either way the accumulation (and
+    # the psum shape upstream) stays f32
+    if wire_scales is not None:
+        xin = _compress.wire_dequant(wire_x, wire_scales, wire_chunk)
+    else:
         xin = xw if wire_x is None else wire_x
+
+    def _msum(counts):
         return uplink.masked_sum(
             xin, slot, band, m, s, counts=counts, block=block
         )
 
     if robust is not None:
-        # robust stats run on DEQUANTIZED values (§13 rule): int-wire
-        # codes expand through the shared dequant before the kernel;
-        # narrow float lanes just cast — order statistics are per value,
-        # so there is no in-tile accumulation to keep quantized
-        if wire_scales is not None:
-            xin = _compress.wire_dequant(wire_x, wire_scales, wire_chunk)
-        else:
-            xin = xw if wire_x is None else wire_x.astype(jnp.float32)
         x_bar, rcnt = uplink.robust_sum(
-            xin, slot, band, m, s, kind=robust[0], k=robust[1],
-            block=block,
+            xin.astype(jnp.float32), slot, band, m, s, kind=robust[0],
+            k=robust[1], block=block,
         )
         covered = (rcnt > 0) if survivor else None
     elif survivor:
@@ -833,8 +825,9 @@ def _shard_comm(
     of the stack assembles all ``s`` owner values per coordinate on
     every shard — bounded by ``s``, never ``(n, d)`` — and the combine
     runs in jnp per shard (kernel grouping is disabled for robust
-    leaves; the HLO regression test pins the collective bound)."""
-    from jax.experimental.shard_map import shard_map
+    leaves across client shards; the HLO regression test pins the
+    collective bound).  On a single client shard every owner row is
+    local: the robust kernel path of the unsharded engine runs there."""
     from jax.sharding import PartitionSpec as P
     from repro.dist import sharding as _shr
 
@@ -871,10 +864,11 @@ def _shard_comm(
 
     # pad the client axis to the dp extent: padded rows are idle (slot -1,
     # zero state) — never owners, never owned — and sliced off after.
-    # jnp.pad, NOT jnp.concatenate: on this jax, GSPMD reshards a concat
-    # feeding a shard_map via a dynamic-update-slice + all-reduce over ALL
-    # mesh axes, writing each block once per model replica and
-    # double-counting the state (measured; pad lowers clean).
+    # jnp.pad, NOT jnp.concatenate: GSPMD reshards a concat feeding a
+    # shard_map through dynamic-update-slices and an extra all-reduce over
+    # the mesh (jax 0.9 on a 4x2 CPU mesh: 2 all-reduces where pad lowers
+    # to 1; an older JAX summed the blocks once per model replica there,
+    # double-counting the state).
     pad = (-n) % dp_total
     dwn = (jnp.ones((n,), bool) if down is None
            else jnp.asarray(down).astype(bool))
@@ -1052,13 +1046,16 @@ def _shard_comm(
         # combiner can still merge the all-reduces on real backends.
         out_x: List[Any] = [None] * len(xs)
         out_h: List[Any] = [None] * len(xs)
-        # robust leaves always take the jnp owner-value exchange: the
-        # kernel masked_sum psums a PARTIAL sum, but order statistics
-        # need the full owner stack on every shard
+        # robust leaves take the jnp owner-value exchange across shards:
+        # the kernel masked_sum psums a PARTIAL sum, but order statistics
+        # need the full owner stack on every shard.  A single client
+        # shard holds every owner row, so there the robust kernel runs.
         covered = [i for i in range(len(xs))
-                   if robust is None and kernels and not tall[i]]
+                   if (robust is None or dp_total == 1) and kernels
+                   and not tall[i]]
         rest = [i for i in range(len(xs)) if i not in covered]
         if covered:
+            from repro.kernels import compress as _compress
             from repro.kernels import uplink
 
             # one workspace (and one d-sized psum) per wire kind: the f32
@@ -1102,16 +1099,20 @@ def _shard_comm(
                 def _msum(counts, _xw=xw, _wx=wx, _wsc=wsc, _wcc=wcc,
                           _band=band_ws):
                     if _wsc is not None:
-                        return uplink.masked_sum_dequant(
-                            _wx, _wsc, _wcc, sl, _band, m, s,
-                            counts=counts, block=block,
-                        )
-                    xin = _xw if _wx is None else _wx
+                        xin = _compress.wire_dequant(_wx, _wsc, _wcc)
+                    else:
+                        xin = _xw if _wx is None else _wx
                     return uplink.masked_sum(
                         xin, sl, _band, m, s, counts=counts, block=block
                     )
 
-                if survivor:
+                if robust is not None:  # one shard: no psum needed
+                    _, h_new_ws, x_new_ws = _pallas_comm(
+                        xw, hw, sl, band_ws, m, s, scale, block, down=dw,
+                        survivor=survivor, wire_x=wx, wire_scales=wsc,
+                        wire_chunk=wcc, xbar_tx=tx, robust=robust,
+                    )
+                elif survivor:
                     num_ws, cnt_ws = _msum(True)
                     xbar_ws, cov_ws = _survivor_bar(
                         _psum(num_ws), _psum(cnt_ws)
@@ -1185,12 +1186,12 @@ def _shard_comm(
     else:
         in_specs = (leaf_specs, leaf_specs, P(dp), P(), P(dp))
         operands = (tuple(xflat), tuple(hflat), slot, client_of, dwn)
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=(leaf_specs, leaf_specs),
-        check_rep=False,
+        check_vma=False,
     )
     xs_out, hs_out = fn(*operands)
     if pad:

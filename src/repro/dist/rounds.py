@@ -229,7 +229,8 @@ def make_round_fn(
     donated); ``L`` is the (host-sampled) number of local steps; ``slot`` is
     the trace row this round writes (``global_round % flush_every``).  The
     callable exposes ``round_fn.cache`` (bucket -> compiled program),
-    ``round_fn.max_L``, ``round_fn.n``, ``round_fn.c``, ``round_fn.elastic``.
+    ``round_fn.lowered()`` (bucket -> its lowering), ``round_fn.max_L``,
+    ``round_fn.n``, ``round_fn.c``, ``round_fn.elastic``.
 
     **Elastic partial participation** (default whenever ``tcfg.c < n``,
     DESIGN.md §11): every chunk gathers the round's ``c`` cohort rows into
@@ -393,6 +394,7 @@ def make_round_fn(
         return RoundCarry(state, t, dk, ck, out_traces)
 
     cache: Dict[Any, Callable] = {}
+    signatures: Dict[Any, Any] = {}  # key -> abstract args of first call
 
     def program(B: int, with_plan: bool, fkey=None):
         key = (B, with_plan, fkey)
@@ -434,8 +436,8 @@ def make_round_fn(
                 raise ValueError("corrupt mask needs an arrived mask")
             for i, B in enumerate(chunks):
                 do_comm = jnp.asarray(i == len(chunks) - 1)
-                carry = program(B, with_plan)(carry, data, do_comm, slot,
-                                              cohort, down)
+                carry = run((B, with_plan, None), carry, data, do_comm,
+                            slot, cohort, down)
             return carry
         # fault-tolerant rounds carry the arrival mask into every chunk
         # (only the comm chunk consumes it) plus the static fault config
@@ -455,13 +457,32 @@ def make_round_fn(
             byz = jnp.asarray(byz).astype(bool)
         for i, B in enumerate(chunks):
             do_comm = jnp.asarray(i == len(chunks) - 1)
-            carry = program(B, with_plan, fkey)(
-                carry, data, do_comm, slot, cohort, down, arrived,
-                corrupt, byz
-            )
+            carry = run((B, with_plan, fkey), carry, data, do_comm, slot,
+                        cohort, down, arrived, corrupt, byz)
         return carry
 
+    def run(key, *args):
+        if key not in signatures and not any(
+                isinstance(a, jax.core.Tracer) for a in jax.tree.leaves(args)):
+            # uncommitted arrays (jnp scalars) go wherever jit puts them:
+            # no sharding, or the lowering would pin them to one device
+            signatures[key] = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(
+                    a.shape, a.dtype,
+                    sharding=a.sharding if getattr(a, "committed", False)
+                    else None,
+                    weak_type=getattr(a, "weak_type", False)),
+                args)
+        return program(*key)(*args)
+
+    def lowered() -> Dict[Any, Any]:
+        """``{bucket: jax.stages.Lowered}``: every program this round_fn
+        has run, lowered again from its first call's shapes and
+        shardings (no device memory) — what the compiler was handed."""
+        return {k: cache[k].lower(*sig) for k, sig in signatures.items()}
+
     round_fn.cache = cache
+    round_fn.lowered = lowered
     round_fn.max_L = max_L
     round_fn.n = n
     round_fn.c = c
@@ -1642,8 +1663,8 @@ def run_rounds_pipelined(
             pipeline_checkpoint_save(
                 os.path.join(checkpoint_dir, f"pipe_step_{rc + 1}"),
                 carry, pend,
-                {"last_dispatch": np.float32(dispatch.get(u, 0.0)),
-                 "last_commit": np.float32(tc),
+                {"last_dispatch": np.float64(dispatch.get(u, 0.0)),
+                 "last_commit": np.float64(tc),
                  "total_steps": np.int32(total_steps)},
                 rc + 1,
             )
@@ -1671,7 +1692,7 @@ def pipeline_checkpoint_save(path: str, carry: RoundCarry, pending,
             "r": np.int32(e["r"]),
             "cohort": (None if e["cohort"] is None
                        else np.asarray(e["cohort"], np.int32)),
-            "dispatch": np.float32(e["dispatch"]),
+            "dispatch": np.float64(e["dispatch"]),
         }
         for e in pending
     )
@@ -1710,13 +1731,13 @@ def pipeline_checkpoint_restore(path: str, *, carry_like: RoundCarry,
         "steps": np.int32(0),
         "r": np.int32(0),
         "cohort": cohort_like,
-        "dispatch": np.float32(0.0),
+        "dispatch": np.float64(0.0),
     }
     like = {
         "carry": carry_like,
         "pending": tuple(entry_like for _ in range(k)),
-        "clock": {"last_dispatch": np.float32(0.0),
-                  "last_commit": np.float32(0.0),
+        "clock": {"last_dispatch": np.float64(0.0),
+                  "last_commit": np.float64(0.0),
                   "total_steps": np.int32(0)},
     }
     return checkpoint.restore(path, like)

@@ -12,8 +12,9 @@ comm step (``dist/comm_ws.py``) evaluate the same closed form, so this
 module's mask generation IS the production comm step's mask generation.
 
 Operands may be flat ``(d,)`` vectors (1-D grid over coordinate blocks,
-``slot`` shaped ``(1,)``) or client-stacked ``(n, d)`` matrices (2-D grid
-with clients as the leading grid axis, ``slot`` shaped ``(n,)``).
+``slot`` shaped ``(1,)``) or client-stacked ``(n, d)`` matrices (1-D grid
+over ``(n, blk)`` coordinate tiles sized by ``fit_block``, ``slot``
+shaped ``(n,)`` and read whole by every tile).
 ``interpret=None`` auto-detects the backend: compiled via Mosaic on TPU,
 interpreter elsewhere.
 """
@@ -26,6 +27,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def resolve_interpret(interpret: Optional[bool]) -> bool:
@@ -49,15 +51,50 @@ def cyclic_band(k, c: int, s: int):
     return (-(s * (k % c))) % c
 
 
+# Scoped VMEM the (n, blk)-tiled kernels ask Mosaic for (v5e has 128 MiB
+# per core; Mosaic's default scope is 16 MiB), and the part of it that
+# the double-buffered tiles plus the in-tile f32 temporaries may take.
+VMEM_LIMIT_BYTES = 48 * 2**20
+VMEM_TILE_BYTES = 32 * 2**20
+# XLA tiles a 1-D f32/int32 array by 1024 elements on TPU, so a (blk,)
+# block of a longer vector must be a multiple of it.
+VEC_TILE = 1024
+
+
+def fit_block(block: int, d: int, n: int, itemsizes, temps: int = 2) -> int:
+    """The coordinate block of an ``(n, blk)``-tiled kernel: the widest
+    multiple of ``VEC_TILE`` (at most ``block``, at least one tile) at
+    which the double-buffered tiles of the ``(n, d)`` operands (one entry
+    of ``itemsizes`` each) plus ``temps`` f32 ``(n, blk)`` temporaries
+    fit ``VMEM_TILE_BYTES``; rows pad to the 8-sublane tile.  ``d``
+    itself when it is narrower (a block equal to the whole axis)."""
+    rows = -(-n // 8) * 8
+    per_col = rows * (2 * sum(itemsizes) + 4 * temps)
+    fit = min(block, VMEM_TILE_BYTES // per_col) // VEC_TILE * VEC_TILE
+    blk = max(VEC_TILE, fit)
+    return d if d <= blk else blk
+
+
+# Mosaic parameters of the ``fit_block``-tiled kernels
+TILED_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
+
 def wire_dequant(codes, scales, chunk_ids):
     """Dequantize int-wire payload lanes: ``codes`` (rows, d) int8 times
     the per-chunk f32 scale each column's ``chunk_ids`` entry selects
-    from ``scales`` (rows, nchunk).  Shared by the uplink kernels (in-
-    tile, f32 accumulation downstream) and the jnp comm paths — the one
-    definition of the wire's dequantization, so the kernel and jnp
-    impls cannot drift (a NaN-poisoned chunk scale propagates the NaN
-    here in both)."""
-    return codes.astype(jnp.float32) * jnp.take(scales, chunk_ids, axis=1)
+    from ``scales`` (rows, nchunk).  The one definition of the wire's
+    dequantization, shared by the jnp comm paths and, ahead of the
+    uplink kernels, the pallas ones, so the kernel and jnp impls cannot
+    drift (a NaN-poisoned chunk scale propagates the NaN in both).
+
+    The scales gather reads the flattened scales at ``row * nchunk +
+    chunk``, a row-major result; ``take(axis=1)`` lays its output out
+    coordinate-major, which the TPU pads along the row axis to 128
+    lanes."""
+    rows, nchunk = scales.shape
+    idx = (jnp.arange(rows, dtype=jnp.int32)[:, None] * nchunk
+           + chunk_ids[None, :])
+    return codes.astype(jnp.float32) * jnp.take(scales.reshape(-1), idx)
 
 
 def _compress_kernel(slot_ref, x_ref, o_ref, *, c: int, s: int, block: int):
@@ -70,9 +107,10 @@ def _compress_kernel(slot_ref, x_ref, o_ref, *, c: int, s: int, block: int):
 
 def _compress2d_kernel(slot_ref, x_ref, o_ref, *, c: int, s: int,
                        block: int):
-    j = pl.program_id(1)
+    j = pl.program_id(0)
     k = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1) + j * block
-    owned = owned_from_band(slot_ref[0], cyclic_band(k, c, s), c, s)
+    owned = owned_from_band(slot_ref[...][:, None], cyclic_band(k, c, s),
+                            c, s)
     x = x_ref[...]
     o_ref[...] = jnp.where(owned, x, jnp.zeros((), x.dtype))
 
@@ -89,38 +127,30 @@ def compress(
     interpret = resolve_interpret(interpret)
     if x.ndim == 2:
         n, d = x.shape
-        blk = min(block, d)
-        pad = (-d) % blk
-        if pad:
-            x = jnp.pad(x, ((0, 0), (0, pad)))
-        n_blocks = x.shape[1] // blk
-        out = pl.pallas_call(
+        blk = fit_block(block, d, n, [x.dtype.itemsize] * 2)
+        return pl.pallas_call(
             functools.partial(_compress2d_kernel, c=c, s=s, block=blk),
-            grid=(n, n_blocks),
+            grid=(pl.cdiv(d, blk),),
             in_specs=[
-                pl.BlockSpec((1,), lambda i, j: (i,)),  # this client's slot
-                pl.BlockSpec((1, blk), lambda i, j: (i, j)),
+                pl.BlockSpec((n,), lambda j: (0,)),  # every client's slot
+                pl.BlockSpec((n, blk), lambda j: (0, j)),
             ],
-            out_specs=pl.BlockSpec((1, blk), lambda i, j: (i, j)),
+            out_specs=pl.BlockSpec((n, blk), lambda j: (0, j)),
             out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+            compiler_params=TILED_PARAMS,
             interpret=interpret,
         )(slot, x)
-        return out[:, :d] if pad else out
 
     d = x.shape[0]
-    pad = (-d) % block
-    if pad:
-        x = jnp.pad(x, (0, pad))
-    n_blocks = x.shape[0] // block
-    out = pl.pallas_call(
-        functools.partial(_compress_kernel, c=c, s=s, block=block),
-        grid=(n_blocks,),
+    blk = min(block, d)
+    return pl.pallas_call(
+        functools.partial(_compress_kernel, c=c, s=s, block=blk),
+        grid=(pl.cdiv(d, blk),),
         in_specs=[
             pl.BlockSpec((1,), lambda i: (0,)),  # slot, broadcast to all tiles
-            pl.BlockSpec((block,), lambda i: (i,)),
+            pl.BlockSpec((blk,), lambda i: (i,)),
         ],
-        out_specs=pl.BlockSpec((block,), lambda i: (i,)),
+        out_specs=pl.BlockSpec((blk,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         interpret=interpret,
     )(slot, x)
-    return out[:d] if pad else out

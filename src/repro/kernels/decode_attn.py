@@ -27,6 +27,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.compress import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -89,7 +91,7 @@ def decode_attention(
     window: Optional[int] = None,
     softcap: Optional[float] = None,
     block_s: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     b, h, hd = q.shape
     S, kvh = k.shape[1], k.shape[2]
@@ -125,6 +127,6 @@ def decode_attention(
             pltpu.VMEM((group, 1), jnp.float32),   # running denominator l
             pltpu.VMEM((group, hd), jnp.float32),  # output accumulator
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(pos_arr, qg, k, v)
     return out.reshape(b, h, hd)

@@ -1,8 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-``interpret`` mode is selected automatically: on the CPU container the
-kernels execute their bodies in the Pallas interpreter (bit-accurate
-validation); on a real TPU backend they compile via Mosaic.
+``interpret`` mode is selected automatically (``compress.resolve_interpret``):
+off-TPU the kernels execute their bodies in the Pallas interpreter
+(bit-accurate validation); on a TPU backend they compile via Mosaic.
 """
 
 from __future__ import annotations
@@ -19,16 +19,12 @@ from repro.kernels import local_step as _local_step
 from repro.kernels import uplink as _uplink
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @partial(jax.jit, static_argnames=("c", "s", "block"))
 def compress(x, slot, c: int, s: int, block: int = 4096):
     """C_i(x): (d,) with slot (1,), or client-stacked (n, d) with slot
     (n,) — the 2-D form runs a grid over clients."""
     return _compress.compress(
-        x, slot, c, s, block=block, interpret=_interpret()
+        x, slot, c, s, block=block
     )
 
 
@@ -36,7 +32,7 @@ def compress(x, slot, c: int, s: int, block: int = 4096):
 def uplink_masked_sum(x, slot, band, m: int, s: int, block: int = 4096):
     """Mask-free UpCom over the (n, d) comm workspace, 1/s rebuild fused."""
     return _uplink.masked_sum(
-        x, slot, band, m, s, block=block, interpret=_interpret()
+        x, slot, band, m, s, block=block
     )
 
 
@@ -47,7 +43,6 @@ def uplink_h_update(x, h, x_bar, slot, band, m: int, s: int, scale: float,
     the rows that receive ``x_bar`` (all rows when None)."""
     return _uplink.h_update(
         x, h, x_bar, slot, band, m, s, scale, down=down, block=block,
-        interpret=_interpret(),
     )
 
 
@@ -55,7 +50,7 @@ def uplink_h_update(x, h, x_bar, slot, band, m: int, s: int, scale: float,
 def fused_local_step(x, g, h, gamma: float, block: int = 65536):
     """x <- x - gamma*(g - h), any shape, storage-dtype preserving."""
     return _local_step.fused_local_step(
-        x, g, h, gamma, block=block, interpret=_interpret()
+        x, g, h, gamma, block=block
     )
 
 
@@ -69,7 +64,6 @@ def decode_attention(
     """Flash-decode GQA attention: q (b,h,hd) vs cache k/v (b,S,kvh,hd)."""
     return _decode_attn.decode_attention(
         q, k, v, pos, window=window, softcap=softcap, block_s=block_s,
-        interpret=_interpret(),
     )
 
 

@@ -12,11 +12,8 @@ or ``(d, c)`` mask is ever materialized in HBM.
               mask read + masked-product materialization.  The payload
               lanes may be the narrow float wire dtype (bf16/f16,
               ``dist/wire.py``); accumulation is always f32.
-  masked_sum_dequant
-              the int-wire variant: (n, d) int8 codes + (n, nchunk) f32
-              per-chunk scales, dequantized per VMEM tile
-              (``compress.wire_dequant``) with f32 accumulation — the
-              client-axis HBM read shrinks to 1 byte per coordinate.
+              Int-wire codes are dequantized ahead of the kernel
+              (``compress.wire_dequant``).
   h_update    the round's state update: reads x, h and the server model
               x_bar once and writes BOTH h_new (control variates, owned
               coordinates only) and the DownCom'd x_new in the same pass —
@@ -26,11 +23,16 @@ or ``(d, c)`` mask is ever materialized in HBM.
               (DESIGN.md §11) only the NEXT round's cohort downloads, so
               idle clients' rows pass through bit-exactly.
 
-Grid: 1-D over coordinate blocks; tiles are ``(n, block)`` — pick ``block``
-so ``n * block * 4B`` tiles fit VMEM (n=512 at the default block=4096 is
-8 MB).  ``interpret=None`` auto-detects the backend (Mosaic on TPU,
-interpreter elsewhere); CPU CI exercises exactly these bodies in interpret
-mode (tests/test_kernels.py), while the CPU production path uses the
+Grid: 1-D over coordinate blocks; tiles are ``(n, blk)``.  ``block`` is
+only a cap: ``compress.fit_block`` narrows it from n and the operand
+dtypes so the double-buffered tiles plus the in-tile f32 temporaries
+stay inside the scoped VMEM the kernels ask for (at n=512 ``h_update``
+runs 1024-wide blocks; the old fixed 4096 ran Mosaic out of VMEM).  A
+ragged last block is a partial grid step, never a padded copy of the
+``(n, d)`` operands.
+``interpret=None`` auto-detects the backend (Mosaic on TPU, interpreter
+elsewhere); CPU CI exercises exactly these bodies in interpret mode
+(tests/test_kernels.py), while the CPU production path uses the
 equivalent fused-jnp workspace math.
 """
 
@@ -44,12 +46,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.compress import (
+    TILED_PARAMS,
+    fit_block,
     owned_from_band,
     resolve_interpret,
-    wire_dequant,
 )
 
-__all__ = ["masked_sum", "masked_sum_dequant", "robust_sum", "h_update"]
+__all__ = ["masked_sum", "robust_sum", "h_update"]
 
 
 def _masked_sum_kernel(slot_ref, band_ref, x_ref, o_ref, *, m: int, s: int):
@@ -77,32 +80,6 @@ def _masked_sum_counts_kernel(
     cnt_ref[...] = owned.astype(jnp.float32).sum(axis=0)
 
 
-def _masked_sum_dequant_kernel(
-    slot_ref, band_ref, chunk_ref, codes_ref, scales_ref, o_ref,
-    *, m: int, s: int,
-):
-    # int-wire lanes: int8 codes dequantized in-tile against the per-
-    # chunk scales (full (n, nchunk) block, tiny next to the codes tile),
-    # then the same masked f32 accumulation as the float-lane kernel
-    owned = owned_from_band(
-        slot_ref[...][:, None], band_ref[...][None, :], m, s
-    )
-    v = wire_dequant(codes_ref[...], scales_ref[...], chunk_ref[...])
-    o_ref[...] = jnp.where(owned, v, 0.0).sum(axis=0) / s
-
-
-def _masked_sum_dequant_counts_kernel(
-    slot_ref, band_ref, chunk_ref, codes_ref, scales_ref, num_ref, cnt_ref,
-    *, m: int, s: int,
-):
-    owned = owned_from_band(
-        slot_ref[...][:, None], band_ref[...][None, :], m, s
-    )
-    v = wire_dequant(codes_ref[...], scales_ref[...], chunk_ref[...])
-    num_ref[...] = jnp.where(owned, v, 0.0).sum(axis=0)
-    cnt_ref[...] = owned.astype(jnp.float32).sum(axis=0)
-
-
 def _robust_sum_kernel(
     slot_ref, band_ref, x_ref, bar_ref, cnt_ref,
     *, m: int, s: int, kind: str, k: int,
@@ -119,28 +96,33 @@ def _robust_sum_kernel(
         slot_ref[...][:, None], band_ref[...][None, :], m, s
     )
     x = x_ref[...].astype(jnp.float32)
+    n = x.shape[0]
     cnt = owned.astype(jnp.int32).sum(axis=0)
     big = jnp.asarray(jnp.inf, jnp.float32)
+    # row index per element: the first hit row is the min row index
+    # among the hits (a client-axis min; Mosaic has no cumsum lowering)
+    rid = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
     active = owned
     order = []  # order[t] = t-th smallest arrived owner value (+inf past cnt)
     for _ in range(s):
         v = jnp.where(active, x, big)
         mn = v.min(axis=0)
         hit = (v == mn[None, :]) & active
-        first = (jnp.cumsum(hit.astype(jnp.int32), axis=0) == 1) & hit
-        active = active & ~first
+        first_row = jnp.where(hit, rid, n).min(axis=0)
+        active = active & (rid != first_row[None, :])
         order.append(mn)
     zero = jnp.zeros((), jnp.float32)
     if kind == "median":
-        loi = jnp.maximum((cnt - 1) // 2, 0)
-        hii = cnt // 2
+        # (cnt - 1) // 2 and cnt // 2 as shifts: cnt >= 0 here
+        loi = jnp.maximum(cnt - 1, 0) >> 1
+        hii = cnt >> 1
         lo = hi = zero
         for t, mn in enumerate(order):
             lo = jnp.where(loi == t, mn, lo)
             hi = jnp.where(hii == t, mn, hi)
         bar = 0.5 * (lo + hi)  # lo == hi at odd counts: exact
     else:  # trimmed
-        k_eff = jnp.clip(jnp.minimum(k, (cnt - 1) // 2), 0)
+        k_eff = jnp.clip(jnp.minimum(k, jnp.maximum(cnt - 1, 0) >> 1), 0)
         num = zero
         for t, mn in enumerate(order):
             use = (t >= k_eff) & (t < cnt - k_eff)
@@ -185,8 +167,33 @@ def _h_update_covered_kernel(
     x_out[...] = jnp.where(down, jnp.broadcast_to(x_bar, x.shape), x)
 
 
-def _pad_cols(a: jax.Array, pad: int) -> jax.Array:
-    return jnp.pad(a, ((0, 0), (0, pad))) if pad else a
+def _col_call(kernel, n: int, d: int, blk: int, in_specs, n_vec_out: int,
+              n_mat_out: int, interpret):
+    """``pallas_call`` over a 1-D grid of ``blk``-wide coordinate blocks
+    (the last one partial when ``blk`` does not divide ``d``) with
+    ``n_vec_out`` f32 ``(d,)`` then ``n_mat_out`` f32 ``(n, d)`` outputs.
+    ``in_specs`` entries are ``"row"`` ((n,) whole), ``"vec"`` ((blk,)
+    slice) or ``"mat"`` ((n, blk) tile)."""
+    spec = {
+        "row": pl.BlockSpec((n,), lambda i: (0,)),
+        "vec": pl.BlockSpec((blk,), lambda i: (i,)),
+        "mat": pl.BlockSpec((n, blk), lambda i: (0, i)),
+    }
+    outs = ["vec"] * n_vec_out + ["mat"] * n_mat_out
+    shapes = [(d,)] * n_vec_out + [(n, d)] * n_mat_out
+    out_specs = tuple(spec[o] for o in outs)
+    out_shape = tuple(jax.ShapeDtypeStruct(sh, jnp.float32) for sh in shapes)
+    if len(outs) == 1:
+        out_specs, out_shape = out_specs[0], out_shape[0]
+    return pl.pallas_call(
+        kernel,
+        grid=(pl.cdiv(d, blk),),
+        in_specs=[spec[i] for i in in_specs],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        compiler_params=TILED_PARAMS,
+        interpret=resolve_interpret(interpret),
+    )
 
 
 def masked_sum(
@@ -207,96 +214,13 @@ def masked_sum(
     per-coordinate arrived-owner count — so the caller can psum both
     and rebuild ``x_bar = num / max(cnt, 1)`` globally."""
     n, d = x.shape
-    blk = min(block, d)
-    pad = (-d) % blk
-    x = _pad_cols(x, pad)
-    band = jnp.pad(band, (0, pad)) if pad else band
-    in_specs = [
-        pl.BlockSpec((n,), lambda i: (0,)),
-        pl.BlockSpec((blk,), lambda i: (i,)),
-        pl.BlockSpec((n, blk), lambda i: (0, i)),
-    ]
-    vec = pl.BlockSpec((blk,), lambda i: (i,))
+    blk = fit_block(block, d, n, [x.dtype.itemsize])
     if counts:
-        num, cnt = pl.pallas_call(
-            functools.partial(_masked_sum_counts_kernel, m=m, s=s),
-            grid=(x.shape[1] // blk,),
-            in_specs=in_specs,
-            out_specs=(vec, vec),
-            out_shape=(
-                jax.ShapeDtypeStruct((x.shape[1],), jnp.float32),
-                jax.ShapeDtypeStruct((x.shape[1],), jnp.float32),
-            ),
-            interpret=resolve_interpret(interpret),
-        )(slot, band, x)
-        return (num[:d], cnt[:d]) if pad else (num, cnt)
-    out = pl.pallas_call(
-        functools.partial(_masked_sum_kernel, m=m, s=s),
-        grid=(x.shape[1] // blk,),
-        in_specs=in_specs,
-        out_specs=vec,
-        out_shape=jax.ShapeDtypeStruct((x.shape[1],), jnp.float32),
-        interpret=resolve_interpret(interpret),
-    )(slot, band, x)
-    return out[:d] if pad else out
-
-
-def masked_sum_dequant(
-    codes: jax.Array,  # (n, d) int8 wire codes (int4 codes fit in int8)
-    scales: jax.Array,  # (n, nchunk) f32 per-chunk scales
-    chunk_ids: jax.Array,  # (d,) int32 scale column per coordinate
-    slot: jax.Array,  # (n,) int32; outside [0, m) -> contributes nothing
-    band: jax.Array,  # (d,) int32 per-coordinate owner band
-    m: int,
-    s: int,
-    *,
-    counts: bool = False,
-    block: int = 4096,
-    interpret: Optional[bool] = None,
-):
-    """``masked_sum`` over int-wire workspace lanes: the (n, d) payload is
-    int8 codes plus per-chunk f32 scales; each tile dequantizes in VMEM
-    (``compress.wire_dequant``) and accumulates in f32, so HBM traffic on
-    the client-stacked axis is 1 byte per coordinate instead of 4.  The
-    ``counts=True`` survivor-aware contract matches ``masked_sum``."""
-    n, d = codes.shape
-    blk = min(block, d)
-    pad = (-d) % blk
-    codes = _pad_cols(codes, pad)
-    if pad:
-        band = jnp.pad(band, (0, pad))
-        chunk_ids = jnp.pad(chunk_ids, (0, pad))
-    nc = scales.shape[1]
-    in_specs = [
-        pl.BlockSpec((n,), lambda i: (0,)),
-        pl.BlockSpec((blk,), lambda i: (i,)),
-        pl.BlockSpec((blk,), lambda i: (i,)),
-        pl.BlockSpec((n, blk), lambda i: (0, i)),
-        pl.BlockSpec((n, nc), lambda i: (0, 0)),
-    ]
-    vec = pl.BlockSpec((blk,), lambda i: (i,))
-    if counts:
-        num, cnt = pl.pallas_call(
-            functools.partial(_masked_sum_dequant_counts_kernel, m=m, s=s),
-            grid=(codes.shape[1] // blk,),
-            in_specs=in_specs,
-            out_specs=(vec, vec),
-            out_shape=(
-                jax.ShapeDtypeStruct((codes.shape[1],), jnp.float32),
-                jax.ShapeDtypeStruct((codes.shape[1],), jnp.float32),
-            ),
-            interpret=resolve_interpret(interpret),
-        )(slot, band, chunk_ids, codes, scales)
-        return (num[:d], cnt[:d]) if pad else (num, cnt)
-    out = pl.pallas_call(
-        functools.partial(_masked_sum_dequant_kernel, m=m, s=s),
-        grid=(codes.shape[1] // blk,),
-        in_specs=in_specs,
-        out_specs=vec,
-        out_shape=jax.ShapeDtypeStruct((codes.shape[1],), jnp.float32),
-        interpret=resolve_interpret(interpret),
-    )(slot, band, chunk_ids, codes, scales)
-    return out[:d] if pad else out
+        kernel = functools.partial(_masked_sum_counts_kernel, m=m, s=s)
+    else:
+        kernel = functools.partial(_masked_sum_kernel, m=m, s=s)
+    return _col_call(kernel, n, d, blk, ["row", "vec", "mat"],
+                     2 if counts else 1, 0, interpret)(slot, band, x)
 
 
 def robust_sum(
@@ -325,29 +249,12 @@ def robust_sum(
         if kind == "trimmed":
             raise ValueError(f"robust_sum needs 0 <= 2k < s (k={k}, s={s})")
     n, d = x.shape
-    blk = min(block, d)
-    pad = (-d) % blk
-    x = _pad_cols(x, pad)
-    band = jnp.pad(band, (0, pad)) if pad else band
-    in_specs = [
-        pl.BlockSpec((n,), lambda i: (0,)),
-        pl.BlockSpec((blk,), lambda i: (i,)),
-        pl.BlockSpec((n, blk), lambda i: (0, i)),
-    ]
-    vec = pl.BlockSpec((blk,), lambda i: (i,))
-    bar, cnt = pl.pallas_call(
-        functools.partial(_robust_sum_kernel, m=m, s=s, kind=kind,
-                          k=int(k)),
-        grid=(x.shape[1] // blk,),
-        in_specs=in_specs,
-        out_specs=(vec, vec),
-        out_shape=(
-            jax.ShapeDtypeStruct((x.shape[1],), jnp.float32),
-            jax.ShapeDtypeStruct((x.shape[1],), jnp.float32),
-        ),
-        interpret=resolve_interpret(interpret),
-    )(slot, band, x)
-    return (bar[:d], cnt[:d]) if pad else (bar, cnt)
+    # the s selection passes keep a few (n, blk) temporaries live at once
+    blk = fit_block(block, d, n, [x.dtype.itemsize], temps=6)
+    kernel = functools.partial(_robust_sum_kernel, m=m, s=s, kind=kind,
+                               k=int(k))
+    return _col_call(kernel, n, d, blk, ["row", "vec", "mat"], 2, 0,
+                     interpret)(slot, band, x)
 
 
 def h_update(
@@ -371,51 +278,17 @@ def h_update(
     (survivor-aware path) additionally masks per-coordinate: coordinates
     with no arrived owner keep both h and x bit-exactly."""
     n, d = x.shape
-    blk = min(block, d)
-    pad = (-d) % blk
-    x, h = _pad_cols(x, pad), _pad_cols(h, pad)
-    band = jnp.pad(band, (0, pad)) if pad else band
-    x_bar = jnp.pad(x_bar, (0, pad)) if pad else x_bar
+    blk = fit_block(block, d, n, [4, 4, 4, 4])  # x, h in; h, x out
     down = (jnp.ones((n,), jnp.int32) if down is None
             else down.astype(jnp.int32))
-    vec = pl.BlockSpec((blk,), lambda i: (i,))
-    mat = pl.BlockSpec((n, blk), lambda i: (0, i))
-    row = pl.BlockSpec((n,), lambda i: (0,))
     if covered is not None:
-        cov = jnp.pad(covered.astype(jnp.int32), (0, pad)) if pad \
-            else covered.astype(jnp.int32)
-        h_new, x_new = pl.pallas_call(
-            functools.partial(
-                _h_update_covered_kernel, m=m, s=s, scale=scale
-            ),
-            grid=(x.shape[1] // blk,),
-            in_specs=[row, row, vec, vec, vec, mat, mat],
-            out_specs=(mat, mat),
-            out_shape=(
-                jax.ShapeDtypeStruct(x.shape, jnp.float32),
-                jax.ShapeDtypeStruct(x.shape, jnp.float32),
-            ),
-            interpret=resolve_interpret(interpret),
-        )(slot, down, band, cov, x_bar, x, h)
+        kernel = functools.partial(
+            _h_update_covered_kernel, m=m, s=s, scale=scale
+        )
+        specs = ["row", "row", "vec", "vec", "vec", "mat", "mat"]
+        args = (slot, down, band, covered.astype(jnp.int32), x_bar, x, h)
     else:
-        h_new, x_new = pl.pallas_call(
-            functools.partial(_h_update_kernel, m=m, s=s, scale=scale),
-            grid=(x.shape[1] // blk,),
-            in_specs=[
-                row,  # slot
-                row,  # down
-                vec,  # band
-                vec,  # x_bar
-                mat,  # x
-                mat,  # h
-            ],
-            out_specs=(mat, mat),
-            out_shape=(
-                jax.ShapeDtypeStruct(x.shape, jnp.float32),
-                jax.ShapeDtypeStruct(x.shape, jnp.float32),
-            ),
-            interpret=resolve_interpret(interpret),
-        )(slot, down, band, x_bar, x, h)
-    if pad:
-        h_new, x_new = h_new[:, :d], x_new[:, :d]
-    return h_new, x_new
+        kernel = functools.partial(_h_update_kernel, m=m, s=s, scale=scale)
+        specs = ["row", "row", "vec", "vec", "mat", "mat"]
+        args = (slot, down, band, x_bar, x, h)
+    return _col_call(kernel, n, d, blk, specs, 0, 2, interpret)(*args)

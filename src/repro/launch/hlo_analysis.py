@@ -298,6 +298,23 @@ def _walk(comp: Computation, comps: Dict[str, Computation], mult: float,
             costs.bytes_accessed += mult * b
 
 
+def max_collective_elems(hlo_text: str) -> int:
+    """Elements of the largest array any collective in ``hlo_text`` moves
+    (each element of a tuple result counts alone; ``-done`` halves of
+    async pairs repeat their ``-start`` and are skipped)."""
+    worst = 0
+    for comp in parse_module(hlo_text).values():
+        for inst in comp.instrs:
+            if (inst.opcode.replace("-start", "") not in _COLLECTIVES
+                    or inst.opcode.endswith("-done")):
+                continue
+            for dt, dims in _SHAPE_RE.findall(inst.type_str):
+                if dt in _DTYPE_BYTES:
+                    worst = max(worst, math.prod(
+                        int(d) for d in dims.split(",") if d))
+    return worst
+
+
 def analyze(hlo_text: str) -> Costs:
     comps = parse_module(hlo_text)
     entry = comps.get("__entry__")

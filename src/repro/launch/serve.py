@@ -42,7 +42,9 @@ def main(argv=None) -> int:
     from repro.configs import registry
     from repro.dist import model_api, sharding
     from repro.launch.mesh import make_host_mesh
+    from repro.launch.runtime import enable_compile_cache
 
+    enable_compile_cache()
     mesh = make_host_mesh(args.data_parallel, args.model_parallel)
     cfg = registry.get_reduced_config(args.arch)
     max_seq = args.prompt_len + args.gen_len

@@ -1,7 +1,10 @@
 """End-to-end TAMUNA-DP training driver.
 
-Runs real training (CPU host mesh by default — the same step functions the
-dry-run lowers for the production mesh).  Round structure follows
+Runs real training on the devices JAX finds: the TPU chips of a TPU host,
+or forced host CPU devices elsewhere (``--data-parallel`` x
+``--model-parallel`` of them; the same step functions the dry-run lowers
+for the production mesh).  The first line printed names the platform, so a
+run that expected a chip and got the CPU says so.  Round structure follows
 Algorithm 1: ``L^(r) ~ Geometric(p)`` local steps then a compressed
 communication step.
 
@@ -28,7 +31,10 @@ import sys
 import time
 
 
-def main(argv=None) -> int:
+def main(argv=None, *, round_fn_hook=None) -> int:
+    """Parse ``argv`` and train.  ``round_fn_hook``, if given, is called
+    with the synchronous driver's round function after the last round
+    (``round_fn.lowered()`` then yields the programs that ran)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma2-2b")
     ap.add_argument("--reduced", action="store_true",
@@ -134,9 +140,14 @@ def main(argv=None) -> int:
     from repro import checkpoint, metrics
     from repro.configs import registry
     from repro.data import DataConfig, SyntheticTokenPipeline, device_sampler
-    from repro.dist import rounds, sharding, tamuna_dp
+    from repro.dist import comm_ws, rounds, sharding, tamuna_dp
     from repro.launch.mesh import make_host_mesh
+    from repro.launch.runtime import device_info, enable_compile_cache
 
+    enable_compile_cache()
+    dev = device_info()
+    print(f"[train] device: platform={dev['platform']} kind={dev['kind']} "
+          f"count={dev['count']}")
     mesh = make_host_mesh(args.data_parallel, args.model_parallel)
     cfg = (
         registry.get_reduced_config(args.arch)
@@ -152,6 +163,8 @@ def main(argv=None) -> int:
         wire_precision=args.wire_precision, wire_down=args.wire_down,
         robust_agg=args.robust_agg, trim_k=args.trim_k,
     )
+    print(f"[train] comm impl: "
+          f"{comm_ws.effective_impl(tcfg.comm_impl, meshed=True, mesh=mesh)}")
     adversarial = args.adversary != "none" and args.f_byz > 0.0
     if args.reputation and not adversarial:
         ap.error("--reputation needs --adversary and --f-byz > 0")
@@ -312,7 +325,10 @@ def main(argv=None) -> int:
         )
         total_steps = last.get("local_steps", 0)
         final_loss = last.get("loss", float("nan"))
+        if round_fn_hook is not None:
+            round_fn_hook(round_fn)
 
+    logger.close()
     dt = time.time() - t0
     print(f"[train] {args.rounds} rounds / {total_steps} local steps "
           f"in {dt:.1f}s; final loss {final_loss:.4f}")

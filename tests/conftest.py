@@ -2,10 +2,6 @@
 smoke tests and benches must see the real single CPU device; multi-device
 tests run through the ``subproc`` fixture, which is where the
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` default lives.
-
-Also installs the offline `hypothesis` fallback (tests/_vendor) when the
-real package is not installed, so the property-test modules collect and run
-on the container without pip access.
 """
 
 import os
@@ -18,11 +14,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SRC = os.path.join(REPO, "src")
 if SRC not in sys.path:
     sys.path.insert(0, SRC)
-
-try:  # pragma: no cover - environment dependent
-    import hypothesis  # noqa: F401
-except ImportError:
-    sys.path.insert(0, os.path.join(REPO, "tests", "_vendor"))
 
 DEFAULT_DEVICES = int(os.environ.get("REPRO_TEST_DEVICES", "8"))
 

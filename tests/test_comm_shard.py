@@ -139,30 +139,13 @@ def test_no_population_sized_collective_in_meshed_pallas(subproc):
     all-reduce failure) is the positive control whose collective scales
     with s*d, validating the parser."""
     subproc("""
-import re
 import dataclasses
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.models.transformer import ModelConfig
 from repro.dist import comm_ws, sharding, tamuna_dp
-
-COLL = re.compile(
-    r"= (?P<res>[^=]*?) (?:all-gather|all-reduce|reduce-scatter|"
-    r"all-to-all)(?:-start)?\\(")
-SHAPE = re.compile(r"(?:f|s|u|pred|bf)[0-9]*\\[([0-9,]*)\\]")
-
-def max_coll_elems(hlo):
-    worst = 0
-    for line in hlo.splitlines():
-        m = COLL.search(line)
-        if not m or "-done" in line.split("(")[0]:
-            continue
-        for dims in SHAPE.findall(m.group("res")):
-            els = 1
-            for d in filter(None, dims.split(",")):
-                els *= int(d)
-            worst = max(worst, els)
-    return worst
+# the largest collective result (tuple results read element by element)
+from repro.launch.hlo_analysis import max_collective_elems as max_coll_elems
 
 mesh = jax.make_mesh((4, 2), ("data", "model"),
                      axis_types=(jax.sharding.AxisType.Auto,) * 2)

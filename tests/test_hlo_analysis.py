@@ -86,6 +86,21 @@ print("OK")
 """, devices=4)
 
 
+def test_max_collective_elems_reads_tuple_results():
+    # XLA prints tuple results with /*index=N*/ comments between elements;
+    # the -done half of an async pair repeats its -start and is skipped
+    hlo = """HloModule m
+
+ENTRY %main (p: f32[8,64]) -> f32[64] {
+  %p = f32[8,64]{1,0} parameter(0)
+  %ag = f32[4,8,64]{2,1,0} all-gather-done(f32[4,8,64]{2,1,0} %x)
+  %ar = (f32[64]{0}, s32[8]{0}, f32[2]{0}, f32[2]{0}, f32[2]{0}, /*index=5*/f32[3,100]{1,0}) all-reduce-start(%a, %b), to_apply=%add
+  ROOT %r = f32[64]{0} all-reduce(f32[64]{0} %y), to_apply=%add
+}
+"""
+    assert H.max_collective_elems(hlo) == 300
+
+
 def test_sliced_fusion_not_charged_full_buffer():
     # gathering 2 rows from a big table must not count the whole table
     table = jax.ShapeDtypeStruct((4096, 512), jnp.float32)

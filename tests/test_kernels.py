@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.kernels import ops, ref
+from repro.kernels import compress, ops, ref, uplink
 
 
 # --------------------------------------------------------------------------
@@ -168,6 +168,81 @@ def test_uplink_kernels_property(n, m, s, d, seed):
         np.asarray(h_new), np.asarray(h_exp), rtol=1e-6, atol=1e-6
     )
     np.testing.assert_array_equal(np.asarray(x_new), np.asarray(x_exp))
+
+
+@pytest.mark.parametrize("kind,k", [("trimmed", 1), ("median", 0)])
+@pytest.mark.parametrize("n,d,m,s", [
+    (8, 3000, 6, 3),    # two idle rows, ragged tail
+    (12, 1500, 12, 5),  # full participation
+])
+def test_uplink_robust_sum_matches_rank_ref(kind, k, n, d, m, s):
+    """Ties included (values rounded to halves): the kernel's first-row
+    tie break and the oracle's row-index ranks pick the same values."""
+    x, _, slot, band = _uplink_operands(n, d, m, 7 * n + d)
+    x = jnp.round(x * 2) / 2
+    bar, cnt = uplink.robust_sum(x, slot, band, m, s, kind=kind, k=k,
+                                 block=1024, interpret=True)
+    bar_exp, cnt_exp = ref.uplink_robust_sum_ref(x, slot, band, m, s,
+                                                 kind, k)
+    np.testing.assert_array_equal(np.asarray(cnt), np.asarray(cnt_exp))
+    np.testing.assert_allclose(np.asarray(bar), np.asarray(bar_exp),
+                               rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("counts", [False, True])
+def test_uplink_masked_sum_of_int_wire_matches_ref(counts):
+    """The int-wire UpCom as the pallas comm runs it: codes through the
+    shared dequant, then the float kernel — against the oracle dequant."""
+    n, d, m, s = 6, 4097, 5, 2
+    _, _, slot, band = _uplink_operands(n, d, m, d)
+    rng = np.random.default_rng(d)
+    codes = jnp.asarray(rng.integers(-127, 128, (n, d)), jnp.int8)
+    scales = jnp.asarray(rng.random((n, -(-d // 256))), jnp.float32)
+    chunk = jnp.arange(d, dtype=jnp.int32) // 256
+    vals = compress.wire_dequant(codes, scales, chunk)
+    np.testing.assert_array_equal(
+        np.asarray(vals), np.asarray(ref.wire_dequant_ref(codes, scales)))
+    out = uplink.masked_sum(vals, slot, band, m, s, counts=counts,
+                            block=1024, interpret=True)
+    exp = ref.uplink_masked_sum_ref(ref.wire_dequant_ref(codes, scales),
+                                    slot, band, m, s, counts=counts)
+    for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(exp)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-6, atol=1e-6)
+
+
+def test_uplink_h_update_covered_keeps_uncovered_coords():
+    n, d, m, s = 6, 4097, 5, 2
+    x, h, slot, band = _uplink_operands(n, d, m, 5 * n + d)
+    x_bar = ref.uplink_masked_sum_ref(x, slot, band, m, s)
+    cov = jnp.asarray(np.random.default_rng(1).random(d) < 0.7)
+    down = jnp.asarray([1, 0, 1, 1, 0, 1], jnp.int32)
+    h_new, x_new = uplink.h_update(x, h, x_bar, slot, band, m, s, 0.25,
+                                   down=down, covered=cov, block=1024,
+                                   interpret=True)
+    h_exp, x_exp = ref.uplink_h_update_ref(x, h, x_bar, slot, band, m, s,
+                                           0.25, down=down, covered=cov)
+    np.testing.assert_allclose(np.asarray(h_new), np.asarray(h_exp),
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(x_new), np.asarray(x_exp))
+    unc = ~np.asarray(cov)
+    np.testing.assert_array_equal(np.asarray(x_new)[:, unc],
+                                  np.asarray(x)[:, unc])
+    np.testing.assert_array_equal(np.asarray(h_new)[:, unc],
+                                  np.asarray(h)[:, unc])
+
+
+@pytest.mark.parametrize("n,d,itemsizes", [
+    (8, 36_501_504, [4]), (512, 1 << 20, [4, 4, 4, 4]), (8, 300, [1, 4]),
+])
+def test_fit_block_is_a_whole_vector_tile_within_vmem(n, d, itemsizes):
+    blk = compress.fit_block(4096, d, n, itemsizes)
+    if blk == d:
+        return
+    assert blk % compress.VEC_TILE == 0 and 0 < blk <= 4096
+    rows = -(-n // 8) * 8
+    tiles = rows * blk * (2 * sum(itemsizes) + 8)
+    assert blk == compress.VEC_TILE or tiles <= compress.VMEM_TILE_BYTES
 
 
 # --------------------------------------------------------------------------

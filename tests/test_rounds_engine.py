@@ -153,6 +153,54 @@ print("OK")
 """, devices=4, timeout=1500)
 
 
+def test_lowered_rebuilds_every_program_that_ran(subproc):
+    """round_fn.lowered() hands back one lowering per compiled bucket, from
+    the shapes and shardings the programs ran with (uncommitted scalars
+    included) — on a dp-sharded mesh, where pinning them fails."""
+    subproc("""
+import numpy as np
+import jax, jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.models.transformer import ModelConfig
+from repro.data import DataConfig, device_sampler
+from repro.data.pipeline import SyntheticTokenPipeline
+from repro.dist import rounds, sharding, tamuna_dp
+
+mesh = jax.make_mesh((4, 1), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,)*2)
+cfg = ModelConfig(family="dense", n_layers=1, d_model=32, n_heads=2,
+                  n_kv_heads=2, d_ff=64, vocab=64, dtype=jnp.float32,
+                  remat=False)
+n = sharding.n_clients(mesh)
+dcfg = DataConfig(seq_len=8, per_client_batch=1, vocab=64, seed=0,
+                  n_clients=n)
+pipe = SyntheticTokenPipeline(dcfg, cfg, mesh)
+tcfg = tamuna_dp.DistTamunaConfig(gamma=0.05, c=3, s=2, p=0.34,
+                                  comm_impl="pallas")
+state = tamuna_dp.init_state(jax.random.key(0), cfg, mesh, tcfg)
+sh = jax.tree.map(lambda s: NamedSharding(mesh, s),
+                  tamuna_dp.state_pspecs(state, cfg, mesh),
+                  is_leaf=lambda x: isinstance(x, P))
+state = jax.device_put(state, sh)
+round_fn = rounds.make_round_fn(
+    cfg, tcfg, mesh, sample_batch=device_sampler(dcfg, cfg, mesh), max_L=4)
+data = pipe.device_data()
+carry = rounds.init_carry(state, jax.random.key(1), 8)
+# traced calls (an outer jit or eval_shape) record nothing
+jax.eval_shape(lambda cr: round_fn(cr, data, 1, 0), carry)
+assert round_fn.lowered() == {}
+for r, L in enumerate((1, 3, 2)):
+    carry = round_fn(carry, data, L, r)
+low = round_fn.lowered()
+assert set(low) == set(round_fn.cache) and len(low) == 2, sorted(low)
+for key, lw in low.items():
+    text = lw.as_text()
+    assert "sdy.sharding" in text or "mhlo.sharding" in text, key
+    lw.compile()
+print("OK")
+""", devices=4, timeout=900)
+
+
 def test_run_rounds_checkpoint_roundtrip_bf16_adamw(subproc):
     """DistTamunaState (bf16 params + AdamW moments) survives
     checkpoint.save/restore mid-run from run_rounds, bit-exactly, and the
