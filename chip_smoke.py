@@ -83,40 +83,6 @@ TOL_STEP_ABS = 1e-6
 TOL_LOSS = 1e-3
 
 
-class CompileClock:
-    """Seconds JAX spent tracing, lowering and compiling (or loading from
-    the persistent cache), and the persistent-cache hits, since ``reset``."""
-
-    EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
-              "/jax/core/compile/jaxpr_to_mlir_module_duration",
-              "/jax/core/compile/backend_compile_duration")
-
-    def __init__(self):
-        import jax
-
-        self.secs, self.hits = 0.0, 0
-        jax.monitoring.register_event_duration_secs_listener(self._dur)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _dur(self, event, secs, **_):
-        if event in self.EVENTS:
-            self.secs += secs
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-
-    def reset(self):
-        self.secs, self.hits = 0.0, 0
-
-
-def peak_bytes() -> int:
-    import jax
-
-    stats = [d.memory_stats() or {} for d in jax.local_devices()]
-    return max(int(s.get("peak_bytes_in_use", 0)) for s in stats)
-
-
 def options(argv) -> dict:
     """``--flag value`` pairs of a train argv (bare flags map to True)."""
     out, i = {}, 0
@@ -290,6 +256,7 @@ def train_run(name, argv, dp, mp, clock, tmp, failures, *, want_impl=None,
     """One ``train.main`` run; returns its per-round losses."""
     import jax
 
+    from bench.clock import peak_bytes
     from repro.dist import comm_ws
     from repro.launch import train
     from repro.launch.mesh import make_host_mesh
@@ -310,7 +277,8 @@ def train_run(name, argv, dp, mp, clock, tmp, failures, *, want_impl=None,
         losses = [float(r["loss"]) for r in csv.DictReader(f)]
     print(f"[smoke] run {name}: impl={impl} mesh=({dp},{mp}) "
           f"wall_s={wall!r} compile_s={clock.secs!r} "
-          f"cache_hits={clock.hits} peak_bytes_in_use={peak_bytes()} "
+          f"cache_hits={clock.hits} "
+          f"peak_bytes={peak_bytes(jax.local_devices())} "
           f"losses={losses}", flush=True)
     if rc != 0:
         failures.append(f"run {name}: train exit code {rc}")
@@ -374,6 +342,8 @@ def main(argv=None) -> int:
         return 1
 
     print(f"[smoke] compile cache: {enable_compile_cache()}", flush=True)
+    from bench.clock import CompileClock
+
     clock = CompileClock()
     failures: list = []
 

@@ -117,6 +117,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.dist import robust as _robust
 from repro.dist import wire as _wire
 
@@ -536,28 +537,29 @@ def _dense_blocked_leaf(xl, hl, slot, m: int, s: int, scale, down=None,
     ``down_quant`` the rebuilt broadcast — see the wire section above."""
     n = xl.shape[0]
     D = int(np.prod(xl.shape[1:]))
-    band = jnp.asarray(_block_leaf_band_np(D, m))[None, :]  # (1, D)
-    sl = slot[:, None]
-    qf = ((sl >= 0) & (((sl + band) % m) < s)).astype(jnp.float32)
-    xf = xl.reshape(n, D).astype(jnp.float32)
-    if sanitize:
-        xf = jnp.where(sl >= 0, xf, 0.0)
-    xq = xf if quant is None else quant(xf)
-    if robust is not None:
-        # robust combine over the dense owner stack: the (n, D) mask IS
-        # the validity mask (robust stats on dequantized values, §13)
-        x_bar, rcnt = _robust.robust_combine_stack(xq, qf > 0, *robust)
-        covered = (rcnt > 0) if survivor else None
-    elif survivor:
-        x_bar, covered = _survivor_bar((xq * qf).sum(axis=0),
-                                       qf.sum(axis=0))
-    else:
-        x_bar, covered = (xq * qf).sum(axis=0) / s, None
-    if down_quant is not None:
-        x_bar = down_quant(x_bar)
-    h_new = hl.reshape(n, D).astype(jnp.float32) + scale * qf * (
-        x_bar[None] - xf
-    )
+    with jax.named_scope(spans.UPLINK):
+        band = jnp.asarray(_block_leaf_band_np(D, m))[None, :]  # (1, D)
+        sl = slot[:, None]
+        qf = ((sl >= 0) & (((sl + band) % m) < s)).astype(jnp.float32)
+        xf = xl.reshape(n, D).astype(jnp.float32)
+        if sanitize:
+            xf = jnp.where(sl >= 0, xf, 0.0)
+        xq = xf if quant is None else quant(xf)
+        if robust is not None:
+            # robust combine over the dense owner stack: the (n, D) mask IS
+            # the validity mask (robust stats on dequantized values, §13)
+            x_bar, rcnt = _robust.robust_combine_stack(xq, qf > 0, *robust)
+            covered = (rcnt > 0) if survivor else None
+        elif survivor:
+            x_bar, covered = _survivor_bar((xq * qf).sum(axis=0),
+                                           qf.sum(axis=0))
+        else:
+            x_bar, covered = (xq * qf).sum(axis=0) / s, None
+        if down_quant is not None:
+            x_bar = down_quant(x_bar)
+        h_new = hl.reshape(n, D).astype(jnp.float32) + scale * qf * (
+            x_bar[None] - xf
+        )
     return (
         _downcom(xl, x_bar, down, covered),
         h_new.astype(hl.dtype).reshape(hl.shape),
@@ -579,32 +581,33 @@ def _dense_cyclic_leaf(xl, hl, slot, c: int, s: int, scale, down=None,
 
     n = xl.shape[0]
     D = int(np.prod(xl.shape[1:]))
-    sl = slot[:, None]
-    q = masks.mask_from_permutation(
-        jnp.arange(c, dtype=jnp.int32), D, c, s
-    ).astype(bool)  # (D, c) template
-    qf = (
-        q.T[jnp.clip(slot, 0)] & (sl >= 0) & (sl < c)
-    ).astype(jnp.float32)
-    xf = xl.reshape(n, D).astype(jnp.float32)
-    if sanitize:
-        xf = jnp.where(sl >= 0, xf, 0.0)
-    xq = xf if quant is None else quant(xf)
-    if robust is not None:
-        # robust combine over the dense owner stack: the (n, D) mask IS
-        # the validity mask (robust stats on dequantized values, §13)
-        x_bar, rcnt = _robust.robust_combine_stack(xq, qf > 0, *robust)
-        covered = (rcnt > 0) if survivor else None
-    elif survivor:
-        x_bar, covered = _survivor_bar((xq * qf).sum(axis=0),
-                                       qf.sum(axis=0))
-    else:
-        x_bar, covered = (xq * qf).sum(axis=0) / s, None
-    if down_quant is not None:
-        x_bar = down_quant(x_bar)
-    h_new = hl.reshape(n, D).astype(jnp.float32) + scale * qf * (
-        x_bar[None] - xf
-    )
+    with jax.named_scope(spans.UPLINK):
+        sl = slot[:, None]
+        q = masks.mask_from_permutation(
+            jnp.arange(c, dtype=jnp.int32), D, c, s
+        ).astype(bool)  # (D, c) template
+        qf = (
+            q.T[jnp.clip(slot, 0)] & (sl >= 0) & (sl < c)
+        ).astype(jnp.float32)
+        xf = xl.reshape(n, D).astype(jnp.float32)
+        if sanitize:
+            xf = jnp.where(sl >= 0, xf, 0.0)
+        xq = xf if quant is None else quant(xf)
+        if robust is not None:
+            # robust combine over the dense owner stack: the (n, D) mask IS
+            # the validity mask (robust stats on dequantized values, §13)
+            x_bar, rcnt = _robust.robust_combine_stack(xq, qf > 0, *robust)
+            covered = (rcnt > 0) if survivor else None
+        elif survivor:
+            x_bar, covered = _survivor_bar((xq * qf).sum(axis=0),
+                                           qf.sum(axis=0))
+        else:
+            x_bar, covered = (xq * qf).sum(axis=0) / s, None
+        if down_quant is not None:
+            x_bar = down_quant(x_bar)
+        h_new = hl.reshape(n, D).astype(jnp.float32) + scale * qf * (
+            x_bar[None] - xf
+        )
     return (
         _downcom(xl, x_bar, down, covered),
         h_new.astype(hl.dtype).reshape(hl.shape),
@@ -662,9 +665,10 @@ def _finish_leaf(xl, hl, xf, x_bar, owned, scale, down=None, covered=None):
     so ``owned`` is already false on every row there)."""
     n = xl.shape[0]
     D = xf.shape[1]
-    h_new = hl.reshape(n, D).astype(jnp.float32) + scale * jnp.where(
-        owned, x_bar[None] - xf, 0.0
-    )
+    with jax.named_scope(spans.UPLINK):
+        h_new = hl.reshape(n, D).astype(jnp.float32) + scale * jnp.where(
+            owned, x_bar[None] - xf, 0.0
+        )
     return (
         _downcom(xl, x_bar, down, covered),
         h_new.astype(hl.dtype).reshape(hl.shape),
@@ -1136,39 +1140,40 @@ def _shard_comm(
                 for j, i in enumerate(idxs):
                     out_x[i], out_h[i] = xs_un[j], hs_un[j]
         for i in rest:
-            if robust is not None:
-                # the (s, d_local)-bounded owner-value exchange: owner
-                # columns derive from the band ((t - band) mod m owns
-                # coordinate k at shift t — the inverse of the shared
-                # (slot + band) mod m < s predicate), each shard fills
-                # the stack rows whose owner it hosts, and ONE psum of
-                # the (s, d_local) stack replicates all owner values —
-                # never an (n, d)-sized collective
-                xf = xqs[i]
-                if tall[i]:
-                    kk = (jnp.asarray(np.arange(gD[i], dtype=np.int32))
-                          if coords[i] is None else coords[i])
-                    colz = jnp.stack(
-                        [kk + t * gD[i] for t in range(s)])
+            with jax.named_scope(spans.UPLINK):
+                if robust is not None:
+                    # the (s, d_local)-bounded owner-value exchange: owner
+                    # columns derive from the band ((t - band) mod m owns
+                    # coordinate k at shift t — the inverse of the shared
+                    # (slot + band) mod m < s predicate), each shard fills
+                    # the stack rows whose owner it hosts, and ONE psum of
+                    # the (s, d_local) stack replicates all owner values —
+                    # never an (n, d)-sized collective
+                    xf = xqs[i]
+                    if tall[i]:
+                        kk = (jnp.asarray(np.arange(gD[i], dtype=np.int32))
+                              if coords[i] is None else coords[i])
+                        colz = jnp.stack(
+                            [kk + t * gD[i] for t in range(s)])
+                    else:
+                        bd = _leaf_band(i, coords[i])
+                        colz = jnp.stack([(t - bd) % m for t in range(s)])
+                    own = cof[colz]  # (s, d_local) global owner row
+                    okm = (jnp.ones(colz.shape, bool) if cok is None
+                           else cok[colz])
+                    loc = (own >= row0) & (own < row0 + rows) & okm
+                    rr = jnp.clip(own - row0, 0, rows - 1)
+                    stack = jnp.where(
+                        loc, jnp.take_along_axis(xf, rr, axis=0), 0.0)
+                    stack = _psum(stack)
+                    x_bar, rcnt = _robust.robust_combine_stack(
+                        stack, okm, *robust)
+                    cov = (rcnt > 0) if survivor else None
+                elif survivor:
+                    num, cnt = local_partial(i, counts=True)
+                    x_bar, cov = _survivor_bar(_psum(num), _psum(cnt))
                 else:
-                    bd = _leaf_band(i, coords[i])
-                    colz = jnp.stack([(t - bd) % m for t in range(s)])
-                own = cof[colz]  # (s, d_local) global owner row
-                okm = (jnp.ones(colz.shape, bool) if cok is None
-                       else cok[colz])
-                loc = (own >= row0) & (own < row0 + rows) & okm
-                rr = jnp.clip(own - row0, 0, rows - 1)
-                stack = jnp.where(
-                    loc, jnp.take_along_axis(xf, rr, axis=0), 0.0)
-                stack = _psum(stack)
-                x_bar, rcnt = _robust.robust_combine_stack(
-                    stack, okm, *robust)
-                cov = (rcnt > 0) if survivor else None
-            elif survivor:
-                num, cnt = local_partial(i, counts=True)
-                x_bar, cov = _survivor_bar(_psum(num), _psum(cnt))
-            else:
-                x_bar, cov = _psum(local_partial(i)), None
+                    x_bar, cov = _psum(local_partial(i)), None
             if wdown:
                 x_bar = _down_quant(
                     kinds[i], wseed, i, gD[i], coords[i], _leaf_axes(i)
@@ -1325,67 +1330,68 @@ def cyclic_comm(
             else:
                 owned = _wrapped_lt(sl - jnp.asarray(band)[None, :], c, s)
             owned = owned & (sl >= 0)
-            if robust is not None:
-                if not faulted and not tall:
-                    # gather-free owner stack: the cyclic owner column
-                    # (s k + t) mod c only depends on k mod c, so stack
-                    # row t is a constant-mask select chain over the
-                    # slot-ordered rows xq[client_of] — all elementwise,
-                    # so the whole combine stays one parallelizable
-                    # fusion (an elementwise consumer of the (s, D)
-                    # take_along_axis form drags the per-element gather
-                    # into a serial loop body and costs ~3x the mean
-                    # step at production widths)
-                    xs = xq[client_of]  # (c, D) row permutation
-                    resid = np.arange(D, dtype=np.int64) % c
-                    masks = [resid == r for r in range(c)]
-                    stack = []
-                    for t in range(s):
-                        y = xs[(s * (c - 1) + t) % c]
-                        for r in range(c - 2, -1, -1):
-                            y = jnp.where(
-                                jnp.asarray(masks[r]),
-                                xs[(s * r + t) % c], y)
-                        stack.append(y)
-                    vals = jnp.stack(stack)
-                    ok = None
-                else:
-                    # robust combine over the (s, D) owner-row gather
-                    # stack (same gathers the mean path reads; tall
-                    # leaves use their explicit owner-column table)
-                    rows = client_of[jnp.asarray(cols)]
-                    vals = jnp.take_along_axis(xq, rows, axis=0)
-                    ok = col_ok[jnp.asarray(cols)] if faulted else None
-                x_bar, rcnt = _robust.robust_combine_stack(
-                    vals, ok, *robust)
-                cov = (rcnt > 0) if survivor else None
-            elif meshed:
-                # client axis sharded across devices: the owner rows live
-                # on other shards, so a gather would all-gather (n, D) --
-                # keep the psum shape (a d-sized all-reduce, the minimum)
-                # with the predicate fused into the local partial sum
-                num = jnp.where(owned, xq, 0.0).sum(axis=0)
-                if survivor:
-                    x_bar, cov = _survivor_bar(
-                        num, owned.astype(jnp.float32).sum(axis=0)
-                    )
-                else:
-                    x_bar, cov = num / s, None
-            else:
-                # sparse UpCom: s row-gathers + 1/s rebuild, O(s D) reads
-                rows = client_of[jnp.asarray(cols)]  # (s, D) owner rows
-                vals = jnp.take_along_axis(xq, rows, axis=0)
-                if faulted:
-                    ok = col_ok[jnp.asarray(cols)]  # (s, D) owner arrived
-                    num = jnp.where(ok, vals, 0.0).sum(axis=0)
+            with jax.named_scope(spans.UPLINK):
+                if robust is not None:
+                    if not faulted and not tall:
+                        # gather-free owner stack: the cyclic owner column
+                        # (s k + t) mod c only depends on k mod c, so stack
+                        # row t is a constant-mask select chain over the
+                        # slot-ordered rows xq[client_of] — all elementwise,
+                        # so the whole combine stays one parallelizable
+                        # fusion (an elementwise consumer of the (s, D)
+                        # take_along_axis form drags the per-element gather
+                        # into a serial loop body and costs ~3x the mean
+                        # step at production widths)
+                        xs = xq[client_of]  # (c, D) row permutation
+                        resid = np.arange(D, dtype=np.int64) % c
+                        masks = [resid == r for r in range(c)]
+                        stack = []
+                        for t in range(s):
+                            y = xs[(s * (c - 1) + t) % c]
+                            for r in range(c - 2, -1, -1):
+                                y = jnp.where(
+                                    jnp.asarray(masks[r]),
+                                    xs[(s * r + t) % c], y)
+                            stack.append(y)
+                        vals = jnp.stack(stack)
+                        ok = None
+                    else:
+                        # robust combine over the (s, D) owner-row gather
+                        # stack (same gathers the mean path reads; tall
+                        # leaves use their explicit owner-column table)
+                        rows = client_of[jnp.asarray(cols)]
+                        vals = jnp.take_along_axis(xq, rows, axis=0)
+                        ok = col_ok[jnp.asarray(cols)] if faulted else None
+                    x_bar, rcnt = _robust.robust_combine_stack(
+                        vals, ok, *robust)
+                    cov = (rcnt > 0) if survivor else None
+                elif meshed:
+                    # client axis sharded across devices: the owner rows live
+                    # on other shards, so a gather would all-gather (n, D) --
+                    # keep the psum shape (a d-sized all-reduce, the minimum)
+                    # with the predicate fused into the local partial sum
+                    num = jnp.where(owned, xq, 0.0).sum(axis=0)
                     if survivor:
                         x_bar, cov = _survivor_bar(
-                            num, ok.astype(jnp.float32).sum(axis=0)
+                            num, owned.astype(jnp.float32).sum(axis=0)
                         )
                     else:
                         x_bar, cov = num / s, None
                 else:
-                    x_bar, cov = vals.sum(axis=0) / s, None
+                    # sparse UpCom: s row-gathers + 1/s rebuild, O(s D) reads
+                    rows = client_of[jnp.asarray(cols)]  # (s, D) owner rows
+                    vals = jnp.take_along_axis(xq, rows, axis=0)
+                    if faulted:
+                        ok = col_ok[jnp.asarray(cols)]  # (s, D) owner arrived
+                        num = jnp.where(ok, vals, 0.0).sum(axis=0)
+                        if survivor:
+                            x_bar, cov = _survivor_bar(
+                                num, ok.astype(jnp.float32).sum(axis=0)
+                            )
+                        else:
+                            x_bar, cov = num / s, None
+                    else:
+                        x_bar, cov = vals.sum(axis=0) / s, None
             if wdown:
                 x_bar = _down_quant(kinds[i], wseed, i, D)(x_bar)
             out_x[i], out_h[i] = _finish_leaf(
@@ -1648,84 +1654,85 @@ def blocked_comm(
         jb = jnp.arange(nb, dtype=jnp.int32)[None, :]
         own_nb = _wrapped_owned(sl, jb, m, s)
         owned = jnp.repeat(own_nb, chunk, axis=1)[:, :D]
-        cov = None
-        if robust is not None:
-            # robust combine over the s contiguous shift-gathers: stack
-            # the per-shift owner rows (the same whole-chunk reads the
-            # mean path accumulates) instead of summing them
-            jf = jnp.arange(nf, dtype=jnp.int32)
-            xm = xq[:, :nf * chunk].reshape(n, nf, chunk)
-            vals_l, ok_l = [], []
-            for t in range(s):
-                cf = (t - jf) % m
-                v = xm[client_of[cf], jf].reshape(-1)
-                okv = (col_ok[cf] if faulted
-                       else jnp.ones((nf,), bool))
-                okv = jnp.repeat(okv, chunk)
-                if tail:
-                    ct = (t - nf) % m
-                    v = jnp.concatenate(
-                        [v, xq[client_of[ct], nf * chunk:]])
-                    okt = (col_ok[ct] if faulted else jnp.bool_(True))
-                    okv = jnp.concatenate(
-                        [okv, jnp.broadcast_to(okt, (tail,))])
-                vals_l.append(v)
-                ok_l.append(okv)
-            x_bar, rcnt = _robust.robust_combine_stack(
-                jnp.stack(vals_l), jnp.stack(ok_l), *robust)
-            if survivor:
-                cov = rcnt > 0
-        elif meshed:
-            # sharded client axis: keep the d-sized all-reduce shape (see
-            # cyclic_comm); the predicate fuses into the partial sum
-            num = jnp.where(owned, xq, 0.0).sum(axis=0)
-            if survivor:
-                x_bar, cov = _survivor_bar(
-                    num, owned.astype(jnp.float32).sum(axis=0)
-                )
-            else:
-                x_bar = num / s
-        else:
-            xm = xq[:, :nf * chunk].reshape(n, nf, chunk)
-            jf = jnp.arange(nf, dtype=jnp.int32)
-            acc = jnp.zeros((nf, chunk), jnp.float32)
-            acc_t = jnp.zeros((tail,), jnp.float32)
-            cnt_f = jnp.zeros((nf,), jnp.float32)
-            cnt_t = jnp.zeros((), jnp.float32)
-            for t in range(s):
-                # owner row of block j at shift t: the client whose slot
-                # is (t - j) mod m — one contiguous chunk per block, the
-                # reduce-scatter shape
-                if faulted:
-                    ok = col_ok[(t - jf) % m]
-                    acc = acc + jnp.where(
-                        ok[:, None], xm[client_of[(t - jf) % m], jf], 0.0
+        with jax.named_scope(spans.UPLINK):
+            cov = None
+            if robust is not None:
+                # robust combine over the s contiguous shift-gathers: stack
+                # the per-shift owner rows (the same whole-chunk reads the
+                # mean path accumulates) instead of summing them
+                jf = jnp.arange(nf, dtype=jnp.int32)
+                xm = xq[:, :nf * chunk].reshape(n, nf, chunk)
+                vals_l, ok_l = [], []
+                for t in range(s):
+                    cf = (t - jf) % m
+                    v = xm[client_of[cf], jf].reshape(-1)
+                    okv = (col_ok[cf] if faulted
+                           else jnp.ones((nf,), bool))
+                    okv = jnp.repeat(okv, chunk)
+                    if tail:
+                        ct = (t - nf) % m
+                        v = jnp.concatenate(
+                            [v, xq[client_of[ct], nf * chunk:]])
+                        okt = (col_ok[ct] if faulted else jnp.bool_(True))
+                        okv = jnp.concatenate(
+                            [okv, jnp.broadcast_to(okt, (tail,))])
+                    vals_l.append(v)
+                    ok_l.append(okv)
+                x_bar, rcnt = _robust.robust_combine_stack(
+                    jnp.stack(vals_l), jnp.stack(ok_l), *robust)
+                if survivor:
+                    cov = rcnt > 0
+            elif meshed:
+                # sharded client axis: keep the d-sized all-reduce shape (see
+                # cyclic_comm); the predicate fuses into the partial sum
+                num = jnp.where(owned, xq, 0.0).sum(axis=0)
+                if survivor:
+                    x_bar, cov = _survivor_bar(
+                        num, owned.astype(jnp.float32).sum(axis=0)
                     )
-                    cnt_f = cnt_f + ok.astype(jnp.float32)
                 else:
-                    acc = acc + xm[client_of[(t - jf) % m], jf]
-                if tail:
-                    if faulted:
-                        ok_t = col_ok[(t - nf) % m]
-                        acc_t = acc_t + jnp.where(
-                            ok_t, xq[client_of[(t - nf) % m],
-                                     nf * chunk:], 0.0
-                        )
-                        cnt_t = cnt_t + ok_t.astype(jnp.float32)
-                    else:
-                        acc_t = acc_t + xq[client_of[(t - nf) % m],
-                                           nf * chunk:]
-            num = jnp.concatenate([acc.reshape(-1), acc_t]) \
-                if tail else acc.reshape(-1)
-            if survivor:
-                cnt = jnp.repeat(cnt_f, chunk)
-                if tail:
-                    cnt = jnp.concatenate(
-                        [cnt, jnp.broadcast_to(cnt_t, (tail,))]
-                    )
-                x_bar, cov = _survivor_bar(num, cnt)
+                    x_bar = num / s
             else:
-                x_bar = num / s
+                xm = xq[:, :nf * chunk].reshape(n, nf, chunk)
+                jf = jnp.arange(nf, dtype=jnp.int32)
+                acc = jnp.zeros((nf, chunk), jnp.float32)
+                acc_t = jnp.zeros((tail,), jnp.float32)
+                cnt_f = jnp.zeros((nf,), jnp.float32)
+                cnt_t = jnp.zeros((), jnp.float32)
+                for t in range(s):
+                    # owner row of block j at shift t: the client whose slot
+                    # is (t - j) mod m — one contiguous chunk per block, the
+                    # reduce-scatter shape
+                    if faulted:
+                        ok = col_ok[(t - jf) % m]
+                        acc = acc + jnp.where(
+                            ok[:, None], xm[client_of[(t - jf) % m], jf], 0.0
+                        )
+                        cnt_f = cnt_f + ok.astype(jnp.float32)
+                    else:
+                        acc = acc + xm[client_of[(t - jf) % m], jf]
+                    if tail:
+                        if faulted:
+                            ok_t = col_ok[(t - nf) % m]
+                            acc_t = acc_t + jnp.where(
+                                ok_t, xq[client_of[(t - nf) % m],
+                                         nf * chunk:], 0.0
+                            )
+                            cnt_t = cnt_t + ok_t.astype(jnp.float32)
+                        else:
+                            acc_t = acc_t + xq[client_of[(t - nf) % m],
+                                               nf * chunk:]
+                num = jnp.concatenate([acc.reshape(-1), acc_t]) \
+                    if tail else acc.reshape(-1)
+                if survivor:
+                    cnt = jnp.repeat(cnt_f, chunk)
+                    if tail:
+                        cnt = jnp.concatenate(
+                            [cnt, jnp.broadcast_to(cnt_t, (tail,))]
+                        )
+                    x_bar, cov = _survivor_bar(num, cnt)
+                else:
+                    x_bar = num / s
         if wdown:
             x_bar = _down_quant(kinds[i], wseed, i, D)(x_bar)
         out_x[i], out_h[i] = _finish_leaf(xl, hl, xf, x_bar, owned, scale,

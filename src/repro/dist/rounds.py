@@ -77,6 +77,7 @@ from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import spans
 from repro.dist import sharding, tamuna_dp
 from repro.dist.tamuna_dp import _as_key
 from repro.models.transformer import ModelConfig
@@ -206,9 +207,12 @@ def _scan_local(local, sample_batch: SampleFn, state, data, dkey, t, B: int,
         st, m = local(st, **batch)
         return (st, tt + 1, acc + m["loss"]), None
 
-    (state, t, loss_sum), _ = jax.lax.scan(
-        body, (state, t, jnp.float32(0.0)), None, length=B
-    )
+    # the scope holds the whole loop (sampling and its bookkeeping too),
+    # so every op of the scan body carries it
+    with jax.named_scope(spans.LOCAL_STEP):
+        (state, t, loss_sum), _ = jax.lax.scan(
+            body, (state, t, jnp.float32(0.0)), None, length=B
+        )
     return state, t, loss_sum
 
 
@@ -300,13 +304,14 @@ def make_round_fn(
             )
 
         if arrived is None:
+            @jax.named_scope(spans.COMM_STEP)
             def with_comm(st):
                 ckey = comm_round_key(ck, st.round)
                 return comm(st, jax.random.key_data(ckey), cohort=cohort,
                             down=down)
 
             state = jax.lax.cond(do_comm, with_comm, lambda st: st, state)
-            new_traces = None
+            fault_out = None
         else:
             # the fault-tolerant comm branch (DESIGN.md §12/§15):
             # corruption and adversarial payloads are injected into the
@@ -320,6 +325,7 @@ def make_round_fn(
             member = jnp.zeros((n,), bool).at[cohort].set(True)
             want_anom = "anomaly" in traces
 
+            @jax.named_scope(spans.COMM_STEP)
             def with_comm(st):
                 ckey = comm_round_key(ck, st.round)
                 stx = st
@@ -363,34 +369,37 @@ def make_round_fn(
                 return (st, jnp.int32(0), jnp.zeros((n,), bool),
                         jnp.zeros((n,), jnp.float32))
 
-            state, arr_cnt, badm, anom = jax.lax.cond(
+            state, *fault_out = jax.lax.cond(
                 do_comm, with_comm, no_comm, state
             )
-            new_traces = {
-                "arrivals": traces["arrivals"].at[slot].set(arr_cnt),
-                "corrupted": traces["corrupted"].at[slot].set(
-                    badm.sum().astype(jnp.int32)
+        with jax.named_scope(spans.TRACES):
+            out_traces = {
+                "loss_sum": traces["loss_sum"].at[slot].add(loss_sum),
+                "steps": traces["steps"].at[slot].add(B),
+                "up_floats": traces["up_floats"].at[slot].set(
+                    state.up_floats
                 ),
-                "bad": traces["bad"].at[slot].set(badm),
+                "down_floats": traces["down_floats"].at[slot].set(
+                    state.down_floats
+                ),
+                "up_bytes": traces["up_bytes"].at[slot].set(state.up_bytes),
+                "down_bytes": traces["down_bytes"].at[slot].set(
+                    state.down_bytes
+                ),
             }
-            if want_anom:
-                new_traces["anomaly"] = traces["anomaly"].at[slot].set(
-                    anom
-                )
-        out_traces = {
-            "loss_sum": traces["loss_sum"].at[slot].add(loss_sum),
-            "steps": traces["steps"].at[slot].add(B),
-            "up_floats": traces["up_floats"].at[slot].set(state.up_floats),
-            "down_floats": traces["down_floats"].at[slot].set(
-                state.down_floats
-            ),
-            "up_bytes": traces["up_bytes"].at[slot].set(state.up_bytes),
-            "down_bytes": traces["down_bytes"].at[slot].set(
-                state.down_bytes
-            ),
-        }
-        if new_traces is not None:
-            out_traces.update(new_traces)
+            if fault_out is not None:
+                arr_cnt, badm, anom = fault_out
+                out_traces.update({
+                    "arrivals": traces["arrivals"].at[slot].set(arr_cnt),
+                    "corrupted": traces["corrupted"].at[slot].set(
+                        badm.sum().astype(jnp.int32)
+                    ),
+                    "bad": traces["bad"].at[slot].set(badm),
+                })
+                if want_anom:
+                    out_traces["anomaly"] = traces["anomaly"].at[slot].set(
+                        anom
+                    )
         return RoundCarry(state, t, dk, ck, out_traces)
 
     cache: Dict[Any, Callable] = {}
@@ -462,18 +471,24 @@ def make_round_fn(
         return carry
 
     def run(key, *args):
-        if key not in signatures and not any(
+        if key in signatures or any(
                 isinstance(a, jax.core.Tracer) for a in jax.tree.leaves(args)):
-            # uncommitted arrays (jnp scalars) go wherever jit puts them:
-            # no sharding, or the lowering would pin them to one device
-            signatures[key] = jax.tree.map(
-                lambda a: jax.ShapeDtypeStruct(
-                    a.shape, a.dtype,
-                    sharding=a.sharding if getattr(a, "committed", False)
-                    else None,
-                    weak_type=getattr(a, "weak_type", False)),
-                args)
-        return program(*key)(*args)
+            return program(*key)(*args)
+        # uncommitted arrays (jnp scalars) go wherever jit puts them:
+        # no sharding, or the lowering would pin them to one device
+        signatures[key] = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype,
+                sharding=a.sharding if getattr(a, "committed", False)
+                else None,
+                weak_type=getattr(a, "weak_type", False)),
+            args)
+        # the first call traces, lowers and compiles (or loads) the program
+        B, with_plan, fkey = key
+        name = (f"{spans.COMPILE}{B}" + (".plan" if with_plan else "")
+                + (".faults" if fkey is not None else ""))
+        with jax.profiler.TraceAnnotation(name):
+            return program(*key)(*args)
 
     def lowered() -> Dict[Any, Any]:
         """``{bucket: jax.stages.Lowered}``: every program this round_fn
@@ -778,9 +793,10 @@ def run_rounds(
                              f"round_fn has n={n}")
 
     start_round = int(state.round) if (plan is not None or faulted) else 0
-    carry = init_carry(state, key, flush_every,
-                       robust_n=n if faulted else 0,
-                       anomaly=rep is not None)
+    with jax.profiler.TraceAnnotation(spans.INIT_CARRY):
+        carry = init_carry(state, key, flush_every,
+                           robust_n=n if faulted else 0,
+                           anomaly=rep is not None)
     q = quorum if quorum is not None else (c // 2 + 1 if c else None)
     byz_mask = (jnp.asarray(faults.byzantine) if faulted and adversarial
                 else None)
@@ -809,9 +825,11 @@ def run_rounds(
     fmeta = []  # per-pending-round host-side fault accounting
     total_steps = 0
     last: Dict[str, Any] = {}
-    for r in range(rounds):
+
+    def one_round(carry, r: int, slot: int):
+        """Round ``r``: its L draw, its cohort and fault resolution
+        (host), its program calls; returns the carry."""
         L = tamuna_dp.sample_round_length(rng, p, max_L=max_L)
-        slot = len(pending)
         g = start_round + r
         if faulted:
             res = resolve(g)
@@ -879,46 +897,57 @@ def run_rounds(
             )
         else:
             carry = round_fn(carry, data, L, slot)
+        return carry
+
+    for r in range(rounds):
+        with jax.profiler.TraceAnnotation(spans.ROUND):
+            carry = one_round(carry, r, len(pending))
         pending.append(r)
         if len(pending) == flush_every or r == rounds - 1:
-            tr = jax.device_get(carry.traces)  # the only host sync
-            for i, gr in enumerate(pending):
-                executed = int(tr["steps"][i])  # device truth, not host L
-                total_steps += executed
-                last = {
-                    "round": gr,
-                    "L": executed,
-                    "loss": float(tr["loss_sum"][i]) / max(executed, 1),
-                    "local_steps": total_steps,
-                    "up_floats": float(tr["up_floats"][i]),
-                    "down_floats": float(tr["down_floats"][i]),
-                    "up_bytes": float(tr["up_bytes"][i]),
-                    "down_bytes": float(tr["down_bytes"][i]),
-                }
-                if faulted:
-                    last.update({
-                        "arrivals": int(tr["arrivals"][i]),
-                        "corrupted": int(tr["corrupted"][i]),
-                        **fmeta[i],
-                    })
-                    if rep is not None:
-                        last["anomaly_max"] = float(tr["anomaly"][i].max())
-                if logger is not None:
-                    logger.log(gr, last)
-            pending = []
-            fmeta = []
-            carry = carry._replace(
-                traces=_zero_traces(flush_every, n if faulted else 0,
-                                    anomaly=rep is not None)
-            )
+            with jax.profiler.TraceAnnotation(spans.DRAIN):
+                tr = jax.device_get(carry.traces)  # the only host sync
+            with jax.profiler.TraceAnnotation(spans.FLUSH):
+                for i, gr in enumerate(pending):
+                    # device truth, not host L
+                    executed = int(tr["steps"][i])
+                    total_steps += executed
+                    last = {
+                        "round": gr,
+                        "L": executed,
+                        "loss": (float(tr["loss_sum"][i])
+                                 / max(executed, 1)),
+                        "local_steps": total_steps,
+                        "up_floats": float(tr["up_floats"][i]),
+                        "down_floats": float(tr["down_floats"][i]),
+                        "up_bytes": float(tr["up_bytes"][i]),
+                        "down_bytes": float(tr["down_bytes"][i]),
+                    }
+                    if faulted:
+                        last.update({
+                            "arrivals": int(tr["arrivals"][i]),
+                            "corrupted": int(tr["corrupted"][i]),
+                            **fmeta[i],
+                        })
+                        if rep is not None:
+                            last["anomaly_max"] = float(
+                                tr["anomaly"][i].max())
+                    if logger is not None:
+                        logger.log(gr, last)
+                pending = []
+                fmeta = []
+                carry = carry._replace(
+                    traces=_zero_traces(flush_every, n if faulted else 0,
+                                        anomaly=rep is not None)
+                )
         if (checkpoint_dir and checkpoint_every
                 and (r + 1) % checkpoint_every == 0):
             from repro import checkpoint
 
-            checkpoint.save(
-                os.path.join(checkpoint_dir, f"step_{r + 1}"),
-                carry.state, r + 1,
-            )
+            with jax.profiler.TraceAnnotation(spans.CHECKPOINT):
+                checkpoint.save(
+                    os.path.join(checkpoint_dir, f"step_{r + 1}"),
+                    carry.state, r + 1,
+                )
     return carry.state, last
 
 
@@ -1025,80 +1054,82 @@ def make_pipelined_round_fn(
             # all-rows body: every row trained during stage, so the
             # DownCom must broadcast (see make_round_fn)
             down = None
-        ckey = jax.random.key_data(comm_round_key(ck, state.round))
-        if arrived is None:
-            state = comm(state, ckey, cohort=cohort, down=down)
-            new_traces = None
-        else:
-            from repro.dist import faults as faults_mod
-            from repro.dist import robust as robust_mod
+        with jax.named_scope(spans.COMM_STEP):
+            ckey = jax.random.key_data(comm_round_key(ck, state.round))
+            if arrived is None:
+                state = comm(state, ckey, cohort=cohort, down=down)
+                arr = None
+            else:
+                from repro.dist import faults as faults_mod
+                from repro.dist import robust as robust_mod
 
-            member = jnp.zeros((n,), bool).at[cohort].set(True)
-            stx = state
-            if corrupt is not None:
-                stx = stx._replace(x=faults_mod.corrupt_rows(
-                    stx.x, corrupt, corrupt_mode, blowup
-                ))
-            arr = arrived & member
-            if byz is not None:
-                stx = stx._replace(x=faults_mod.adversarial_rows(
-                    stx.x, byz & arr, arr & ~byz, adversary,
-                    byz_scale=byz_scale, byz_z=byz_z,
-                ))
-            if guard:
-                bad = faults_mod.nonfinite_clients(
-                    stx.x, guard_max_abs
-                ) & member
-                if guard_mode == "adaptive":
-                    bad = bad | (robust_mod.magnitude_outliers(
-                        stx.x, arr & ~bad
-                    ) & member)
-                arr = arr & ~bad
-                stx = stx._replace(x=jax.tree.map(
-                    lambda a: jnp.where(
-                        bad.reshape((n,) + (1,) * (a.ndim - 1)),
-                        jnp.zeros((), a.dtype), a,
-                    ),
-                    stx.x,
-                ))
-            else:
-                bad = jnp.zeros((n,), bool)
-            if comm_stats is not None and "uncovered" in traces:
-                state, stats = comm_stats(stx, ckey, cohort=cohort,
-                                          down=down, arrived=arr,
-                                          correct=correct)
-                unc = stats["uncovered"]
-            else:
-                state = comm(stx, ckey, cohort=cohort, down=down,
-                             arrived=arr, correct=correct)
-                unc = None
-            new_traces = {
-                "arrivals": traces["arrivals"].at[slot].set(
-                    arr.sum().astype(jnp.int32)
+                member = jnp.zeros((n,), bool).at[cohort].set(True)
+                stx = state
+                if corrupt is not None:
+                    stx = stx._replace(x=faults_mod.corrupt_rows(
+                        stx.x, corrupt, corrupt_mode, blowup
+                    ))
+                arr = arrived & member
+                if byz is not None:
+                    stx = stx._replace(x=faults_mod.adversarial_rows(
+                        stx.x, byz & arr, arr & ~byz, adversary,
+                        byz_scale=byz_scale, byz_z=byz_z,
+                    ))
+                if guard:
+                    bad = faults_mod.nonfinite_clients(
+                        stx.x, guard_max_abs
+                    ) & member
+                    if guard_mode == "adaptive":
+                        bad = bad | (robust_mod.magnitude_outliers(
+                            stx.x, arr & ~bad
+                        ) & member)
+                    arr = arr & ~bad
+                    stx = stx._replace(x=jax.tree.map(
+                        lambda a: jnp.where(
+                            bad.reshape((n,) + (1,) * (a.ndim - 1)),
+                            jnp.zeros((), a.dtype), a,
+                        ),
+                        stx.x,
+                    ))
+                else:
+                    bad = jnp.zeros((n,), bool)
+                if comm_stats is not None and "uncovered" in traces:
+                    state, stats = comm_stats(stx, ckey, cohort=cohort,
+                                              down=down, arrived=arr,
+                                              correct=correct)
+                    unc = stats["uncovered"]
+                else:
+                    state = comm(stx, ckey, cohort=cohort, down=down,
+                                 arrived=arr, correct=correct)
+                    unc = None
+        with jax.named_scope(spans.TRACES):
+            out_traces = {
+                "loss_sum": traces["loss_sum"].at[slot].set(loss),
+                "steps": traces["steps"].at[slot].set(steps),
+                "up_floats": traces["up_floats"].at[slot].set(
+                    state.up_floats
                 ),
-                "corrupted": traces["corrupted"].at[slot].set(
-                    bad.sum().astype(jnp.int32)
+                "down_floats": traces["down_floats"].at[slot].set(
+                    state.down_floats
                 ),
-                "bad": traces["bad"].at[slot].set(bad),
+                "up_bytes": traces["up_bytes"].at[slot].set(state.up_bytes),
+                "down_bytes": traces["down_bytes"].at[slot].set(
+                    state.down_bytes
+                ),
             }
-            if unc is not None:
-                new_traces["uncovered"] = traces["uncovered"].at[slot].set(
-                    unc
-                )
-        out_traces = {
-            "loss_sum": traces["loss_sum"].at[slot].set(loss),
-            "steps": traces["steps"].at[slot].set(steps),
-            "up_floats": traces["up_floats"].at[slot].set(state.up_floats),
-            "down_floats": traces["down_floats"].at[slot].set(
-                state.down_floats
-            ),
-            "up_bytes": traces["up_bytes"].at[slot].set(state.up_bytes),
-            "down_bytes": traces["down_bytes"].at[slot].set(
-                state.down_bytes
-            ),
-        }
-        if new_traces is not None:
-            out_traces.update(new_traces)
+            if arr is not None:
+                out_traces.update({
+                    "arrivals": traces["arrivals"].at[slot].set(
+                        arr.sum().astype(jnp.int32)
+                    ),
+                    "corrupted": traces["corrupted"].at[slot].set(
+                        bad.sum().astype(jnp.int32)
+                    ),
+                    "bad": traces["bad"].at[slot].set(bad),
+                })
+                if unc is not None:
+                    out_traces["uncovered"] = traces["uncovered"].at[
+                        slot].set(unc)
         return RoundCarry(state, t, dk, ck, out_traces)
 
     cache: Dict[Any, Callable] = {}
@@ -1399,8 +1430,9 @@ def run_rounds_pipelined(
     q = quorum if quorum is not None else c // 2 + 1
     coverage = bool(getattr(engine, "coverage", False)) and robust
     r0 = int(state.round)
-    carry = init_carry(state, key, flush_every,
-                       robust_n=n if robust else 0, coverage=coverage)
+    with jax.profiler.TraceAnnotation(spans.INIT_CARRY):
+        carry = init_carry(state, key, flush_every,
+                           robust_n=n if robust else 0, coverage=coverage)
     ck0 = np.asarray(jax.device_get(carry.comm_key))
 
     def host_cohort(g: int, attempt: int = 0) -> np.ndarray:
@@ -1491,183 +1523,190 @@ def run_rounds_pipelined(
                 tamuna_dp.sample_round_length(rng, p, max_L=max_L)
 
     for u in range(u0, rounds + tau):
-        if u < rounds:
-            # ---- stage round u
-            L = tamuna_dp.sample_round_length(rng, p, max_L=max_L)
-            g = r0 + u
-            if sync_equiv:
-                co = np.asarray(resolve(g)["cohort"])
-            elif engine.elastic:
-                co = resolve_cohort(g, busy_mask())
-            elif plan is not None:
-                co = np.asarray(plan.cohort(g))
-            else:
-                co = None
-            carry, buf = engine.stage(carry, data, L, cohort=co)
-            d = max(committime.get(u - tau - 1, 0.0),
-                    dispatch.get(u - 1, 0.0))
-            dispatch[u] = d
-            pend.append({"r": u, "cohort": co, "buf": buf, "dispatch": d})
-        rc = u - tau
-        if not (0 <= rc < rounds):
-            continue
-        # ---- commit round rc
-        ent = pend.pop(0)
-        g = r0 + rc
-        co, buf = ent["cohort"], ent["buf"]
-        if engine.elastic:
-            if sync_equiv:
-                down = resolve(g + 1)["member"]
-            else:
-                nxt = resolve_cohort(g + tau + 1, busy_mask())
-                down = np.zeros(n, bool)
-                down[nxt] = True
-        else:
-            down = None
-        kw: Dict[str, Any] = {}
-        meta: Dict[str, Any] = {"staleness": tau}
-        if sync_equiv:
-            res = resolve(g)
-            arr_off = arr_offsets(g, buf["steps"], res["retries"])
-            arr = ent["dispatch"] + arr_off
-            cutoff = (float(arr[res["arrived"]].max())
-                      if res["arrived"].any() else ent["dispatch"])
-            cutoff += res["backoff"]
-            kw = dict(
-                arrived=res["arrived"],
-                corrupt=(res["corrupt"]
-                         if faults.model.p_corrupt > 0 else None),
-                byz=byz_mask,
-                correct=(policy != "wait_all"), guard=bool(guard),
-                guard_mode=guard_mode,
-                corrupt_mode=faults.model.corrupt_mode,
-                blowup=faults.model.blowup, guard_max_abs=guard_max_abs,
-                adversary=faults.model.adversary,
-                byz_scale=faults.model.byz_scale,
-                byz_z=faults.model.byz_z,
-            )
-            meta.update(
-                retries=res["retries"], backoff_s=res["backoff"],
-                quorum_miss=res["quorum_miss"],
-                admitted=int(res["arrived"].sum()), late_dropped=0,
-            )
-        elif robust:
-            member = np.zeros(n, bool)
-            member[co] = True
-            dropped = (faults.drops(g, 0) if faults is not None
-                       else np.zeros(n, bool))
-            finite = member & ~dropped
-            arr = np.where(finite,
-                           ent["dispatch"] + arr_offsets(g, buf["steps"]),
-                           np.inf)
-            if policy == "wait_all":
-                cutoff = (float(arr[finite].max()) if finite.any()
-                          else ent["dispatch"])
-                admitted = finite
-            elif policy == "quorum":
-                kq = min(q, int(finite.sum()))
-                if kq == 0:
-                    cutoff, admitted = ent["dispatch"], np.zeros(n, bool)
+        # one pipeline step: stage round u, commit round u - tau
+        with jax.profiler.TraceAnnotation(spans.ROUND):
+            if u < rounds:
+                # ---- stage round u
+                L = tamuna_dp.sample_round_length(rng, p, max_L=max_L)
+                g = r0 + u
+                if sync_equiv:
+                    co = np.asarray(resolve(g)["cohort"])
+                elif engine.elastic:
+                    co = resolve_cohort(g, busy_mask())
+                elif plan is not None:
+                    co = np.asarray(plan.cohort(g))
                 else:
-                    cutoff = float(np.sort(arr[finite])[kq - 1])
-                    admitted = finite & (arr <= cutoff)
+                    co = None
+                carry, buf = engine.stage(carry, data, L, cohort=co)
+                d = max(committime.get(u - tau - 1, 0.0),
+                        dispatch.get(u - 1, 0.0))
+                dispatch[u] = d
+                pend.append({"r": u, "cohort": co, "buf": buf, "dispatch": d})
+            rc = u - tau
+            if not (0 <= rc < rounds):
+                continue
+            # ---- commit round rc
+            ent = pend.pop(0)
+            g = r0 + rc
+            co, buf = ent["cohort"], ent["buf"]
+            if engine.elastic:
+                if sync_equiv:
+                    down = resolve(g + 1)["member"]
+                else:
+                    nxt = resolve_cohort(g + tau + 1, busy_mask())
+                    down = np.zeros(n, bool)
+                    down[nxt] = True
             else:
-                # deadline cuts on simulated ARRIVAL time here (the
-                # synchronous driver cuts on the raw per-round draw)
-                cutoff = ent["dispatch"] + float(deadline)
-                admitted = finite & (arr <= cutoff)
-            kw = dict(
-                arrived=admitted,
-                corrupt=(faults.corrupts(g, 0) & member
-                         if faults is not None
-                         and faults.model.p_corrupt > 0 else None),
-                byz=byz_mask,
-                correct=(policy != "wait_all"), guard=bool(guard),
-                guard_mode=guard_mode,
-                corrupt_mode=(faults.model.corrupt_mode
-                              if faults is not None else "nan"),
-                blowup=(faults.model.blowup
-                        if faults is not None else 1e8),
-                guard_max_abs=guard_max_abs,
-                adversary=(faults.model.adversary
-                           if faults is not None else "none"),
-                byz_scale=(faults.model.byz_scale
-                           if faults is not None else -10.0),
-                byz_z=(faults.model.byz_z
-                       if faults is not None else 1.5),
-            )
-            meta.update(
-                retries=0, backoff_s=0.0,
-                quorum_miss=int(policy == "quorum"
-                                and int(finite.sum()) < q),
-                admitted=int(admitted.sum()),
-                late_dropped=int((finite & ~admitted).sum()),
-            )
-        else:
-            # no admission needed (everyone arrives): the clock still
-            # waits for the slowest member — the wait_all barrier
-            off = arr_offsets(g, buf["steps"])
-            if co is not None:
+                down = None
+            kw: Dict[str, Any] = {}
+            meta: Dict[str, Any] = {"staleness": tau}
+            if sync_equiv:
+                res = resolve(g)
+                arr_off = arr_offsets(g, buf["steps"], res["retries"])
+                arr = ent["dispatch"] + arr_off
+                cutoff = (float(arr[res["arrived"]].max())
+                          if res["arrived"].any() else ent["dispatch"])
+                cutoff += res["backoff"]
+                kw = dict(
+                    arrived=res["arrived"],
+                    corrupt=(res["corrupt"]
+                             if faults.model.p_corrupt > 0 else None),
+                    byz=byz_mask,
+                    correct=(policy != "wait_all"), guard=bool(guard),
+                    guard_mode=guard_mode,
+                    corrupt_mode=faults.model.corrupt_mode,
+                    blowup=faults.model.blowup, guard_max_abs=guard_max_abs,
+                    adversary=faults.model.adversary,
+                    byz_scale=faults.model.byz_scale,
+                    byz_z=faults.model.byz_z,
+                )
+                meta.update(
+                    retries=res["retries"], backoff_s=res["backoff"],
+                    quorum_miss=res["quorum_miss"],
+                    admitted=int(res["arrived"].sum()), late_dropped=0,
+                )
+            elif robust:
                 member = np.zeros(n, bool)
                 member[co] = True
-                cutoff = ent["dispatch"] + (
-                    float(off[member].max()) if member.any() else 0.0
+                dropped = (faults.drops(g, 0) if faults is not None
+                           else np.zeros(n, bool))
+                finite = member & ~dropped
+                arr = np.where(finite,
+                               ent["dispatch"] + arr_offsets(g, buf["steps"]),
+                               np.inf)
+                if policy == "wait_all":
+                    cutoff = (float(arr[finite].max()) if finite.any()
+                              else ent["dispatch"])
+                    admitted = finite
+                elif policy == "quorum":
+                    kq = min(q, int(finite.sum()))
+                    if kq == 0:
+                        cutoff, admitted = ent["dispatch"], np.zeros(n, bool)
+                    else:
+                        cutoff = float(np.sort(arr[finite])[kq - 1])
+                        admitted = finite & (arr <= cutoff)
+                else:
+                    # deadline cuts on simulated ARRIVAL time here (the
+                    # synchronous run_rounds cuts on the raw per-round draw)
+                    cutoff = ent["dispatch"] + float(deadline)
+                    admitted = finite & (arr <= cutoff)
+                kw = dict(
+                    arrived=admitted,
+                    corrupt=(faults.corrupts(g, 0) & member
+                             if faults is not None
+                             and faults.model.p_corrupt > 0 else None),
+                    byz=byz_mask,
+                    correct=(policy != "wait_all"), guard=bool(guard),
+                    guard_mode=guard_mode,
+                    corrupt_mode=(faults.model.corrupt_mode
+                                  if faults is not None else "nan"),
+                    blowup=(faults.model.blowup
+                            if faults is not None else 1e8),
+                    guard_max_abs=guard_max_abs,
+                    adversary=(faults.model.adversary
+                               if faults is not None else "none"),
+                    byz_scale=(faults.model.byz_scale
+                               if faults is not None else -10.0),
+                    byz_z=(faults.model.byz_z
+                           if faults is not None else 1.5),
                 )
-                meta.update(admitted=int(member.sum()), late_dropped=0)
+                meta.update(
+                    retries=0, backoff_s=0.0,
+                    quorum_miss=int(policy == "quorum"
+                                    and int(finite.sum()) < q),
+                    admitted=int(admitted.sum()),
+                    late_dropped=int((finite & ~admitted).sum()),
+                )
             else:
-                cutoff = ent["dispatch"] + (
-                    float(off.max()) if off.size else 0.0
-                )
-                meta.update(admitted=n, late_dropped=0)
-        tc = max(committime.get(rc - 1, 0.0), cutoff)
-        committime[rc] = tc
-        carry = engine.commit(carry, buf, len(window), cohort=co,
-                              down=down, **kw)
-        meta.update({
-            "round": rc, "dispatch_s": ent["dispatch"], "commit_s": tc,
-            "round_latency_s": tc - ent["dispatch"],
-        })
-        window.append(meta)
+                # no admission needed (everyone arrives): the clock still
+                # waits for the slowest member — the wait_all barrier
+                off = arr_offsets(g, buf["steps"])
+                if co is not None:
+                    member = np.zeros(n, bool)
+                    member[co] = True
+                    cutoff = ent["dispatch"] + (
+                        float(off[member].max()) if member.any() else 0.0
+                    )
+                    meta.update(admitted=int(member.sum()), late_dropped=0)
+                else:
+                    cutoff = ent["dispatch"] + (
+                        float(off.max()) if off.size else 0.0
+                    )
+                    meta.update(admitted=n, late_dropped=0)
+            tc = max(committime.get(rc - 1, 0.0), cutoff)
+            committime[rc] = tc
+            carry = engine.commit(carry, buf, len(window), cohort=co,
+                                  down=down, **kw)
+            meta.update({
+                "round": rc, "dispatch_s": ent["dispatch"], "commit_s": tc,
+                "round_latency_s": tc - ent["dispatch"],
+            })
+            window.append(meta)
         drained = False
         if len(window) == flush_every or rc == rounds - 1:
-            tr = jax.device_get(carry.traces)  # the only host sync
-            for i, m in enumerate(window):
-                executed = int(tr["steps"][i])
-                total_steps += executed
-                last = {
-                    "round": m["round"],
-                    "L": executed,
-                    "loss": float(tr["loss_sum"][i]) / max(executed, 1),
-                    "local_steps": total_steps,
-                    "up_floats": float(tr["up_floats"][i]),
-                    "down_floats": float(tr["down_floats"][i]),
-                    "up_bytes": float(tr["up_bytes"][i]),
-                    "down_bytes": float(tr["down_bytes"][i]),
-                }
-                if robust:
-                    last["arrivals"] = int(tr["arrivals"][i])
-                    last["corrupted"] = int(tr["corrupted"][i])
-                    if "uncovered" in tr:
-                        last["uncovered"] = int(tr["uncovered"][i])
-                last.update({k: v for k, v in m.items() if k != "round"})
-                if logger is not None:
-                    logger.log(m["round"], last)
-            window = []
-            carry = carry._replace(traces=_zero_traces(
-                flush_every, n if robust else 0, coverage
-            ))
+            with jax.profiler.TraceAnnotation(spans.DRAIN):
+                tr = jax.device_get(carry.traces)  # the only host sync
+            with jax.profiler.TraceAnnotation(spans.FLUSH):
+                for i, m in enumerate(window):
+                    executed = int(tr["steps"][i])
+                    total_steps += executed
+                    last = {
+                        "round": m["round"],
+                        "L": executed,
+                        "loss": (float(tr["loss_sum"][i])
+                                 / max(executed, 1)),
+                        "local_steps": total_steps,
+                        "up_floats": float(tr["up_floats"][i]),
+                        "down_floats": float(tr["down_floats"][i]),
+                        "up_bytes": float(tr["up_bytes"][i]),
+                        "down_bytes": float(tr["down_bytes"][i]),
+                    }
+                    if robust:
+                        last["arrivals"] = int(tr["arrivals"][i])
+                        last["corrupted"] = int(tr["corrupted"][i])
+                        if "uncovered" in tr:
+                            last["uncovered"] = int(tr["uncovered"][i])
+                    last.update({k: v for k, v in m.items()
+                                 if k != "round"})
+                    if logger is not None:
+                        logger.log(m["round"], last)
+                window = []
+                carry = carry._replace(traces=_zero_traces(
+                    flush_every, n if robust else 0, coverage
+                ))
             drained = True
         if (drained and checkpoint_dir and checkpoint_every
                 and (rc + 1) % checkpoint_every == 0
                 and len(pend) == tau and rc + 1 < rounds):
-            pipeline_checkpoint_save(
-                os.path.join(checkpoint_dir, f"pipe_step_{rc + 1}"),
-                carry, pend,
-                {"last_dispatch": np.float64(dispatch.get(u, 0.0)),
-                 "last_commit": np.float64(tc),
-                 "total_steps": np.int32(total_steps)},
-                rc + 1,
-            )
+            with jax.profiler.TraceAnnotation(spans.CHECKPOINT):
+                pipeline_checkpoint_save(
+                    os.path.join(checkpoint_dir, f"pipe_step_{rc + 1}"),
+                    carry, pend,
+                    {"last_dispatch": np.float64(dispatch.get(u, 0.0)),
+                     "last_commit": np.float64(tc),
+                     "total_steps": np.int32(total_steps)},
+                    rc + 1,
+                )
     return carry.state, last
 
 

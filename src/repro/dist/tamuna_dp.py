@@ -45,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from repro import spans
 from repro.core import masks, theory
 from repro.dist import comm_ws, model_api, robust as _robust, \
     sharding, wire
@@ -342,6 +343,7 @@ def member_mask(cohort: jax.Array, n: int) -> jax.Array:
     return jnp.zeros((n,), bool).at[cohort].set(True)
 
 
+@jax.named_scope(spans.COHORT_GATHER)
 def gather_cohort(state: DistTamunaState,
                   cohort: jax.Array) -> DistTamunaState:
     """Gather the cohort's rows of x / h / opt moments into a compact
@@ -363,6 +365,7 @@ def gather_cohort(state: DistTamunaState,
     )
 
 
+@jax.named_scope(spans.COHORT_SCATTER)
 def scatter_cohort(state: DistTamunaState, compact: DistTamunaState,
                    cohort: jax.Array) -> DistTamunaState:
     """Scatter a compact cohort state back into the full ``(n, ...)``

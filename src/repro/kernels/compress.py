@@ -139,6 +139,7 @@ def compress(
             out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
             compiler_params=TILED_PARAMS,
             interpret=interpret,
+            name="compress2d",
         )(slot, x)
 
     d = x.shape[0]
@@ -153,4 +154,5 @@ def compress(
         out_specs=pl.BlockSpec((blk,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         interpret=interpret,
+        name="compress",
     )(slot, x)

@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import spans
 from repro.kernels.compress import (
     TILED_PARAMS,
     fit_block,
@@ -168,12 +169,13 @@ def _h_update_covered_kernel(
 
 
 def _col_call(kernel, n: int, d: int, blk: int, in_specs, n_vec_out: int,
-              n_mat_out: int, interpret):
+              n_mat_out: int, interpret, name: str):
     """``pallas_call`` over a 1-D grid of ``blk``-wide coordinate blocks
     (the last one partial when ``blk`` does not divide ``d``) with
     ``n_vec_out`` f32 ``(d,)`` then ``n_mat_out`` f32 ``(n, d)`` outputs.
     ``in_specs`` entries are ``"row"`` ((n,) whole), ``"vec"`` ((blk,)
-    slice) or ``"mat"`` ((n, blk) tile)."""
+    slice) or ``"mat"`` ((n, blk) tile).  The call runs under the
+    ``tamuna.uplink`` scope and carries the kernel's ``name``."""
     spec = {
         "row": pl.BlockSpec((n,), lambda i: (0,)),
         "vec": pl.BlockSpec((blk,), lambda i: (i,)),
@@ -185,7 +187,7 @@ def _col_call(kernel, n: int, d: int, blk: int, in_specs, n_vec_out: int,
     out_shape = tuple(jax.ShapeDtypeStruct(sh, jnp.float32) for sh in shapes)
     if len(outs) == 1:
         out_specs, out_shape = out_specs[0], out_shape[0]
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=(pl.cdiv(d, blk),),
         in_specs=[spec[i] for i in in_specs],
@@ -193,7 +195,9 @@ def _col_call(kernel, n: int, d: int, blk: int, in_specs, n_vec_out: int,
         out_shape=out_shape,
         compiler_params=TILED_PARAMS,
         interpret=resolve_interpret(interpret),
+        name=name,
     )
+    return jax.named_scope(spans.UPLINK)(call)
 
 
 def masked_sum(
@@ -220,7 +224,9 @@ def masked_sum(
     else:
         kernel = functools.partial(_masked_sum_kernel, m=m, s=s)
     return _col_call(kernel, n, d, blk, ["row", "vec", "mat"],
-                     2 if counts else 1, 0, interpret)(slot, band, x)
+                     2 if counts else 1, 0, interpret,
+                     "masked_sum_counts" if counts else "masked_sum")(
+                         slot, band, x)
 
 
 def robust_sum(
@@ -254,7 +260,7 @@ def robust_sum(
     kernel = functools.partial(_robust_sum_kernel, m=m, s=s, kind=kind,
                                k=int(k))
     return _col_call(kernel, n, d, blk, ["row", "vec", "mat"], 2, 0,
-                     interpret)(slot, band, x)
+                     interpret, f"robust_sum_{kind}")(slot, band, x)
 
 
 def h_update(
@@ -287,8 +293,11 @@ def h_update(
         )
         specs = ["row", "row", "vec", "vec", "vec", "mat", "mat"]
         args = (slot, down, band, covered.astype(jnp.int32), x_bar, x, h)
+        name = "h_update_covered"
     else:
         kernel = functools.partial(_h_update_kernel, m=m, s=s, scale=scale)
         specs = ["row", "row", "vec", "vec", "mat", "mat"]
         args = (slot, down, band, x_bar, x, h)
-    return _col_call(kernel, n, d, blk, specs, 0, 2, interpret)(*args)
+        name = "h_update"
+    return _col_call(kernel, n, d, blk, specs, 0, 2, interpret,
+                     name)(*args)

@@ -83,6 +83,16 @@ def main(argv=None, *, round_fn_hook=None) -> int:
     ap.add_argument("--flush-every", type=int, default=10,
                     help="fused path: drain device metric traces every "
                          "this many rounds")
+    ap.add_argument("--profile-dir", default="",
+                    help="fused synchronous rounds: write jax.profiler "
+                         "traces to DIR/setup and DIR/rounds.  The first "
+                         "k*k+1 rounds, for k bucket lengths (1, 2, 4, ... "
+                         "up to --max-L), take fixed lengths in place of "
+                         "drawn ones, so the run trains another trajectory "
+                         "than without the flag.  DIR/setup holds those "
+                         "rounds, each program's trace, lowering and "
+                         "compile under its tamuna.compile.<bucket> span; "
+                         "DIR/rounds holds the rest, with nothing compiled")
     ap.add_argument("--pipeline", action="store_true",
                     help="pipelined round engine (DESIGN.md §14): overlap "
                          "round t+1's local compute with round t's "
@@ -137,7 +147,7 @@ def main(argv=None, *, round_fn_hook=None) -> int:
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro import checkpoint, metrics
+    from repro import checkpoint, metrics, spans
     from repro.configs import registry
     from repro.data import DataConfig, SyntheticTokenPipeline, device_sampler
     from repro.dist import comm_ws, rounds, sharding, tamuna_dp
@@ -171,6 +181,12 @@ def main(argv=None, *, round_fn_hook=None) -> int:
     if adversarial and (args.no_fuse or args.pipeline):
         ap.error("--adversary runs on the fused synchronous driver "
                  "(drop --no-fuse/--pipeline)")
+    warm = _warm_lengths(args.max_L)
+    if args.profile_dir and (args.no_fuse or args.pipeline
+                             or args.rounds <= len(warm)):
+        ap.error(f"--profile-dir traces the fused synchronous rounds "
+                 f"(drop --no-fuse/--pipeline) after {len(warm)} "
+                 f"warm rounds (raise --rounds)")
 
     state = tamuna_dp.init_state(jax.random.key(args.seed), cfg, mesh,
                                  tcfg, n=n)
@@ -201,10 +217,13 @@ def main(argv=None, *, round_fn_hook=None) -> int:
         # scattered back (idle clients do nothing; the DownCom broadcasts
         # here — the per-step escape hatch keeps the simpler eager form).
         local_step = jax.jit(
-            tamuna_dp.make_local_step(cfg, tcfg), donate_argnums=(0,)
+            jax.named_scope(spans.LOCAL_STEP)(
+                tamuna_dp.make_local_step(cfg, tcfg)),
+            donate_argnums=(0,),
         )
         comm_step = jax.jit(
-            tamuna_dp.make_comm_step(cfg, tcfg, mesh, n=n),
+            jax.named_scope(spans.COMM_STEP)(
+                tamuna_dp.make_comm_step(cfg, tcfg, mesh, n=n)),
             donate_argnums=(0,),
         )
         key = jax.random.key(args.seed + 1)
@@ -309,20 +328,38 @@ def main(argv=None, *, round_fn_hook=None) -> int:
             if args.reputation:
                 fkw["plan"] = cohort_mod.CohortPlan(args.seed, n, tcfg.c)
                 fkw["reputation"] = True
-        state, last = rounds.run_rounds(
-            state,
-            round_fn=round_fn,
-            data=pipe.device_data(),
-            key=jax.random.key(args.seed + 1),
-            rounds=args.rounds,
-            rng=rng,
-            p=tcfg.p,
-            flush_every=args.flush_every,
-            logger=logger,
-            checkpoint_dir=args.checkpoint_dir or None,
-            checkpoint_every=args.checkpoint_every,
-            **fkw,
-        )
+        lengths = (_ProfiledLengths(rng, warm, args.profile_dir)
+                   if args.profile_dir else rng)
+        try:
+            if args.profile_dir:
+                lengths.start()
+            # eager arrays (a fresh carry's counters, the trace reset) take
+            # the mesh's sharding, as the round programs' outputs do, so a
+            # trace reset gives no program another signature
+            with jax.set_mesh(mesh):
+                state, last = rounds.run_rounds(
+                    state,
+                    round_fn=round_fn,
+                    data=pipe.device_data(),
+                    key=jax.random.key(args.seed + 1),
+                    rounds=args.rounds,
+                    rng=lengths,
+                    p=tcfg.p,
+                    flush_every=args.flush_every,
+                    logger=logger,
+                    checkpoint_dir=args.checkpoint_dir or None,
+                    checkpoint_every=args.checkpoint_every,
+                    **fkw,
+                )
+            if args.profile_dir:
+                jax.block_until_ready(state)
+        finally:
+            if args.profile_dir:
+                lengths.close()
+        if args.profile_dir:
+            print(f"[train] profiles of set-up (rounds 0.."
+                  f"{len(warm) - 1}) and of rounds {len(warm)}.."
+                  f"{args.rounds - 1} in {args.profile_dir}")
         total_steps = last.get("local_steps", 0)
         final_loss = last.get("loss", float("nan"))
         if round_fn_hook is not None:
@@ -333,6 +370,69 @@ def main(argv=None, *, round_fn_hook=None) -> int:
     print(f"[train] {args.rounds} rounds / {total_steps} local steps "
           f"in {dt:.1f}s; final loss {final_loss:.4f}")
     return 0
+
+
+def _warm_lengths(max_L: int) -> list:
+    """Round lengths that call each bucket program after each one (and
+    the first after a fresh carry): a program's outputs need not share
+    its inputs' shardings, so a program compiles once for each program
+    whose carry it takes.  The pairs of k buckets follow each other in
+    k*k + 1 rounds (the prefer-largest de Bruijn sequence)."""
+    buckets = [1 << b for b in range(max_L.bit_length())]
+    seq, used = [0], set()
+    while True:
+        nxt = [b for b in reversed(range(len(buckets)))
+               if (seq[-1], b) not in used]
+        if not nxt:
+            return [buckets[b] for b in seq]
+        used.add((seq[-1], nxt[0]))
+        seq.append(nxt[0])
+
+
+class _ProfiledLengths:
+    """The round lengths of a ``--profile-dir`` run, and its two traces.
+
+    The first rounds take the ``warm`` lengths (``_warm_lengths``), so
+    every round program compiles in them for every carry it meets; the
+    later rounds take ``rng``'s geometric draws.  ``start`` opens
+    ``<dir>/setup``, which holds the fresh carry and the warm rounds,
+    each program's first call under its ``tamuna.compile.<bucket>``
+    span.  ``run_rounds`` draws a round's length before it calls the
+    round's program, so the first geometric draw closes it and opens
+    ``<dir>/rounds``, which holds the rest: the round of that draw has
+    its program calls there and its ``tamuna.round`` span in neither
+    trace.  ``close`` ends the open trace."""
+
+    def __init__(self, rng, warm, profile_dir: str):
+        self.rng, self.warm, self.dir = rng, list(warm), profile_dir
+        self.phase = None
+
+    def _switch(self, phase) -> None:
+        import jax
+
+        if self.phase is not None:
+            jax.profiler.stop_trace()
+        self.phase = phase
+        if phase is not None:
+            # host spans and JAX's own annotations (its lowering and
+            # compile among them), not every Python call
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(os.path.join(self.dir, phase),
+                                     profiler_options=opts)
+
+    def start(self) -> None:
+        self._switch("setup")
+
+    def geometric(self, p: float) -> int:
+        if self.warm:
+            return self.warm.pop(0)
+        if self.phase == "setup":
+            self._switch("rounds")
+        return int(self.rng.geometric(p))
+
+    def close(self) -> None:
+        self._switch(None)
 
 
 if __name__ == "__main__":

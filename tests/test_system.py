@@ -71,6 +71,59 @@ def test_train_driver_no_fuse_elastic(tmp_path):
     assert "final loss" in out
 
 
+def test_train_driver_profile_dir(tmp_path):
+    """``--profile-dir`` at one bucket length: ``setup`` holds the fresh
+    carry and the two warm rounds (the program after a fresh carry and
+    after itself), the program's trace, lowering and compile under
+    ``tamuna.compile.1``; ``rounds`` holds the later rounds and nothing
+    lowered or compiled.  Round 2's draw switches the traces, so its
+    ``tamuna.round`` span is in neither."""
+    import collections
+    import glob
+
+    from jax.profiler import ProfileData
+
+    from repro import spans
+    from repro.launch import train
+
+    # each program after each, in k*k + 1 rounds for k bucket lengths
+    assert train._warm_lengths(1) == [1, 1]
+    assert train._warm_lengths(2) == [1, 2, 2, 1, 1]
+    assert len(train._warm_lengths(8)) == 17
+    prof = tmp_path / "prof"
+    out = _run([
+        "-m", "repro.launch.train", "--arch", "gemma2-2b", "--reduced",
+        "--rounds", "6", "--seq-len", "8", "--per-client-batch", "1",
+        "--data-parallel", "1", "--model-parallel", "1",
+        "--clients", "2", "--cohort", "2", "--max-L", "1",
+        "--flush-every", "2", "--profile-dir", str(prof),
+    ], devices=1)
+    assert "final loss" in out
+
+    def host_events(phase):
+        path, = glob.glob(str(prof / phase / "plugins" / "profile" / "*"
+                              / "*.xplane.pb"))
+        return [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                for plane in ProfileData.from_file(path).planes
+                if plane.name.startswith("/host:")
+                for line in plane.lines for ev in line.events]
+
+    def span_counts(events):
+        return collections.Counter(n for n, _, _ in events
+                                   if n.startswith(spans.PREFIX))
+
+    warm, later = host_events("setup"), host_events("rounds")
+    assert span_counts(warm) == {
+        spans.INIT_CARRY: 1, spans.ROUND: 2, spans.DRAIN: 1, spans.FLUSH: 1,
+        f"{spans.COMPILE}1": 1}
+    lowering = "lower_sharding_computation"  # JAX's own annotation
+    (s, e), = [(s, e) for n, s, e in warm if n == f"{spans.COMPILE}1"]
+    assert any(n == lowering and s <= a <= b <= e for n, a, b in warm)
+    assert span_counts(later) == {spans.ROUND: 3, spans.DRAIN: 2,
+                                  spans.FLUSH: 2}
+    assert not any(n == lowering for n, _, _ in later)
+
+
 def test_serve_driver_end_to_end():
     out = _run([
         "-m", "repro.launch.serve", "--arch", "rwkv6-7b", "--reduced",
