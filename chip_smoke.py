@@ -287,6 +287,8 @@ def train_run(name, argv, dp, mp, clock, tmp, failures, *, want_impl=None,
     if want_impl is not None and impl != want_impl:
         failures.append(f"run {name}: impl {impl}, want {want_impl}")
     if opt["--comm-impl"] == "auto" and ran:
+        print(f"[smoke] run {name}: comm route coords "
+              f"{ran[0].route_coords}", flush=True)
         t0 = time.perf_counter()
         ops = program_ops(ran[0])
         print(f"[smoke] run {name}: round program ops {ops} "

@@ -50,20 +50,34 @@ truth:
 
   ``meshed=True`` + a ``mesh`` handle: the **shard-resident engine**
   (DESIGN.md §10).  The whole comm step runs inside ``shard_map`` over
-  the client-hosting (dp) mesh axes: each shard packs only its *local*
-  client rows into a per-shard workspace and runs the uplink kernels on
-  them (TPU; off-TPU the per-shard math is fused jnp — coarse per-block
-  chunk gathers for the blocked template, masked local partials for the
-  cyclic one), and the shards combine with d-sized ``psum``s of the
-  ``1/s``-folded partials — one for the packed kernel workspace, per
-  leaf on the jnp path — the reduce-scatter-shaped minimum, never an
-  ``(n, d)``-sized collective.  ``h_update``/DownCom then run per shard
-  on local rows reading the combined ``x_bar`` once.  Ownership bands for
-  model-sharded leaves are recomputed per shard from the global
-  coordinate index (``sharding.spec_dim_axes`` offsets), so tensor
-  parallelism keeps its d/model-sized partial.  This is the layer PR 3
-  deferred: ``effective_impl("pallas", meshed=True, mesh=...)`` no longer
-  demotes.
+  the client-hosting (dp) mesh axes, each shard on its *local* client
+  rows, and every leaf takes one of three routes (``_leaf_routes``,
+  decided by what the call shows; ``route_coords`` counts them):
+
+    leaf       the TPU path for the f32 wire: the leaf-native kernels
+               (``uplink.leaf_masked_sum`` / ``leaf_h_update``) reduce
+               and update each leaf of ``LEAF_MIN`` coordinates a row or
+               more in its own layout, ownership from the leaf-local
+               coordinate index inside the kernel — no band table, no
+               packed copy, x and h updated in place;
+    workspace  the per-shard packed workspace and its kernels, now only
+               for the quantized wire (its chunk scales lie over the
+               packed flat axis), robust combiners on one client shard,
+               and model-sharded leaves (bands from the global index);
+    jnp        fused jnp per leaf — coarse per-block chunk gathers for
+               the blocked template, masked local partials for the
+               cyclic one: leaves under ``LEAF_MIN``, tall leaves, robust
+               leaves across client shards, and every leaf off-TPU.
+
+  The shards combine with d-sized ``psum``s of the ``1/s``-folded
+  partials — one per workspace, per leaf on the other routes — the
+  reduce-scatter-shaped minimum, never an ``(n, d)``-sized collective.
+  The h-update/DownCom then run per shard on local rows reading the
+  combined ``x_bar`` once.  Ownership bands for model-sharded leaves are
+  recomputed per shard from the global coordinate index
+  (``sharding.spec_dim_axes`` offsets), so tensor parallelism keeps its
+  d/model-sized partial.  ``effective_impl("pallas", meshed=True,
+  mesh=...)`` runs this engine.
 
 One band table encodes BOTH templates:
 
@@ -785,6 +799,106 @@ def _shard_coords(local_trail: tuple, global_trail: tuple, entries: tuple,
     return jnp.broadcast_to(k, tuple(local_trail)).reshape(-1)
 
 
+def _leaf_comm(xl, hl, slot, m: int, s: int, scale, template: str, down,
+               survivor: bool, psum):
+    """One leaf through the leaf-native kernels, in its own layout: the
+    local rows' UpCom partial (``leaf_masked_sum``), ``psum`` over the
+    client shards, the rebuild, and the in-place h-update + DownCom
+    (``leaf_h_update``).  No band table and no packed copy."""
+    from repro.kernels import uplink
+
+    rows = xl.shape[0]
+    R, C = uplink.leaf_view(xl.shape[1:])
+    x3, h3 = xl.reshape(rows, R, C), hl.reshape(rows, R, C)
+    if survivor:
+        num, cnt = uplink.leaf_masked_sum(x3, slot, m, s, template=template,
+                                          counts=True)
+        x_bar, cov = _survivor_bar(psum(num), psum(cnt))
+    else:
+        x_bar = psum(uplink.leaf_masked_sum(x3, slot, m, s,
+                                            template=template))
+        cov = None
+    h_new, x_new = uplink.leaf_h_update(
+        x3, h3, x_bar, slot, m, s, float(scale), template=template,
+        down=down, covered=cov)
+    return x_new.reshape(xl.shape), h_new.reshape(hl.shape)
+
+
+# Leaves below this many coordinates a row (norm scales, biases) take the
+# per-leaf jnp route even where the kernels are on: a kernel launch there
+# costs more than the leaf.
+LEAF_MIN = 1 << 16
+ROUTES = ("leaf", "workspace", "jnp")
+
+
+def _leaf_routes(dims: Sequence[int], split: Sequence[bool], template: str,
+                 m: int, s: int, *, kernels: bool, wirep: Optional[str],
+                 robust, dp_total: int) -> List[str]:
+    """Each leaf's route through the shard engine, from what the call
+    shows: ``"leaf"`` (the leaf-native kernels, in the leaf's own
+    layout), ``"workspace"`` (packed with the other leaves of its wire
+    kind) or ``"jnp"`` (fused jnp per leaf).  With the kernels on, an
+    f32-wire, mean-combined leaf that is not split over model axes takes
+    the leaf kernels (the jnp route below ``LEAF_MIN`` coordinates); the
+    quantized wire (its per-chunk scales lie over the packed flat axis),
+    robust combiners and model-sharded leaves (bands from the global
+    coordinate index) keep the workspace.  Tall cyclic leaves, robust
+    leaves across client shards (the kernels psum a PARTIAL sum, but an
+    order statistic needs the whole owner stack on every shard; one
+    client shard holds every owner row, so there the robust kernel
+    runs), and every leaf with the kernels off take the jnp route."""
+    out = []
+    for D, sp in zip(dims, split):
+        tall = template == "cyclic" and D * s < m
+        if not kernels or tall or (robust is not None and dp_total > 1):
+            out.append("jnp")
+        elif wirep is not None or robust is not None or sp:
+            out.append("workspace")
+        else:
+            out.append("leaf" if D >= LEAF_MIN else "jnp")
+    return out
+
+
+def _model_split(trail: Sequence[tuple], mesh) -> List[bool]:
+    """Per leaf: is any trailing dim split over mesh axes of size > 1
+    (its shard_map block then is not the whole leaf)."""
+    from repro.dist import sharding as _shr
+
+    def factor(entry):
+        return int(np.prod([mesh.shape[a]
+                            for a in _shr.spec_dim_axes(entry)] or [1]))
+
+    return [any(factor(e) > 1 for e in tr) for tr in trail]
+
+
+def route_coords(x: Any, template: str, m: int, s: int, *,
+                 impl: Optional[str], mesh, pspecs=None,
+                 wire: Optional[str] = None, robust=None,
+                 shard_kernels: Optional[bool] = None) -> dict:
+    """Coordinates of one client row each route of the meshed comm step
+    takes (``ROUTES``; ``_leaf_routes``), for stacked state ``x`` (arrays
+    or shape structs): what ``make_comm_step`` reports as
+    ``fn.route_coords``.  An impl other than the shard engine runs every
+    leaf through jnp."""
+    from repro.dist import sharding as _shr
+
+    xflat = jax.tree.leaves(x)
+    dims = [int(np.prod(a.shape[1:])) for a in xflat]
+    out = dict.fromkeys(ROUTES, 0)
+    if effective_impl(impl, meshed=True, mesh=mesh) != "pallas":
+        out["jnp"] = sum(dims)
+        return out
+    dp_total = int(np.prod([mesh.shape[a]
+                            for a in _shr.dp_axis_names(mesh)] or [1]))
+    routes = _leaf_routes(
+        dims, _model_split(_leaf_trail_specs(xflat, pspecs), mesh),
+        template, m, s, kernels=_use_shard_kernels(shard_kernels),
+        wirep=_wire_policy(wire), robust=robust, dp_total=dp_total)
+    for D, r in zip(dims, routes):
+        out[r] += D
+    return out
+
+
 def _shard_comm(
     x: Any,
     h: Any,
@@ -808,15 +922,16 @@ def _shard_comm(
 ) -> Tuple[Any, Any]:
     """The shard-resident comm step: one ``shard_map`` over the dp axes.
 
-    Per shard: UpCom partials over the LOCAL client rows only — Pallas
-    ``masked_sum`` on the per-shard workspace (TPU), or fused jnp off-TPU
-    (coarse whole-chunk gathers for the blocked template's contiguous
-    ownership, masked local-row sums for the cyclic one — see
-    ``local_partial`` for the measured why) — then the shards combine
-    with d-sized ``psum``s of the ``1/s``-folded partials (one for the
-    packed kernel workspace; per leaf on the jnp path — measured, see the
-    body comment), and ``h_update`` + the DownCom broadcast run per shard
-    on local rows.  No ``(n, d)``-sized collective appears at any point
+    Per shard: UpCom partials over the LOCAL client rows only, per leaf
+    route (``_leaf_routes``): the leaf-native kernels in each leaf's own
+    layout (``_leaf_comm``), Pallas ``masked_sum`` on a per-shard
+    workspace, or fused jnp (coarse whole-chunk gathers for the blocked
+    template's contiguous ownership, masked local-row sums for the cyclic
+    one — see ``local_partial`` for the measured why) — then the shards
+    combine with d-sized ``psum``s of the ``1/s``-folded partials (one
+    per workspace; per leaf on the other routes — measured, see the body
+    comment), and the h-update + the DownCom broadcast run per shard on
+    local rows.  No ``(n, d)``-sized collective appears at any point
     (HLO-regression-tested); the client axis is padded to the dp extent
     with idle rows when it does not divide.
 
@@ -904,6 +1019,9 @@ def _shard_comm(
         _wire.resolve_kind(D, wirep) if wirep is not None else "f32"
         for D in gD
     ]
+    routes = _leaf_routes(gD, _model_split(trail, mesh), template, m, s,
+                          kernels=kernels, wirep=wirep, robust=robust,
+                          dp_total=dp_total)
 
     def _leaf_axes(i):
         names = []
@@ -1050,14 +1168,12 @@ def _shard_comm(
         # combiner can still merge the all-reduces on real backends.
         out_x: List[Any] = [None] * len(xs)
         out_h: List[Any] = [None] * len(xs)
-        # robust leaves take the jnp owner-value exchange across shards:
-        # the kernel masked_sum psums a PARTIAL sum, but order statistics
-        # need the full owner stack on every shard.  A single client
-        # shard holds every owner row, so there the robust kernel runs.
-        covered = [i for i in range(len(xs))
-                   if (robust is None or dp_total == 1) and kernels
-                   and not tall[i]]
-        rest = [i for i in range(len(xs)) if i not in covered]
+        covered = [i for i, r in enumerate(routes) if r == "workspace"]
+        rest = [i for i, r in enumerate(routes) if r == "jnp"]
+        for i in (i for i, r in enumerate(routes) if r == "leaf"):
+            out_x[i], out_h[i] = _leaf_comm(
+                xs[i], hs[i], sl, m, s, scale, template, dw, survivor,
+                _psum)
         if covered:
             from repro.kernels import compress as _compress
             from repro.kernels import uplink

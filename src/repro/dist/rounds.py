@@ -234,7 +234,9 @@ def make_round_fn(
     the trace row this round writes (``global_round % flush_every``).  The
     callable exposes ``round_fn.cache`` (bucket -> compiled program),
     ``round_fn.lowered()`` (bucket -> its lowering), ``round_fn.max_L``,
-    ``round_fn.n``, ``round_fn.c``, ``round_fn.elastic``.
+    ``round_fn.n``, ``round_fn.c``, ``round_fn.elastic``,
+    ``round_fn.route_coords`` (the comm step's coordinates a row per
+    route, ``comm_ws.route_coords``).
 
     **Elastic partial participation** (default whenever ``tcfg.c < n``,
     DESIGN.md §11): every chunk gathers the round's ``c`` cohort rows into
@@ -502,6 +504,8 @@ def make_round_fn(
     round_fn.n = n
     round_fn.c = c
     round_fn.elastic = elastic
+    # (a comm step that a caller wraps need not carry the counter)
+    round_fn.route_coords = getattr(comm, "route_coords", None)
     return round_fn
 
 

@@ -528,6 +528,15 @@ def make_comm_step(
         frac = surv.astype(jnp.float32) / c
         return up_total * frac, up_bytes_total * frac
 
+    # coordinates of a client row per comm route (leaf-native kernels,
+    # packed workspace, per-leaf jnp): how much of the state the
+    # leaf-native kernels cover, read off the same dispatch the step runs
+    route_coords = comm_ws.route_coords(
+        stacked_struct, "blocked" if tcfg.uplink == "block_rs" else "cyclic",
+        c, s, impl=impl, mesh=mesh, pspecs=stacked_specs,
+        wire=tcfg.wire_precision, robust=rspec,
+    )
+
     def wire_seed_(key):
         """The round's uint32 quantization seed, derived off the comm key
         on a folded-away stream: the ``jax.random.split`` draws for
@@ -575,6 +584,7 @@ def make_comm_step(
             )}
 
         fn.wire_kinds = kinds
+        fn.route_coords = route_coords
         return fn
 
     def fn(state: DistTamunaState, key: jax.Array,
@@ -617,6 +627,7 @@ def make_comm_step(
         )}
 
     fn.wire_kinds = kinds
+    fn.route_coords = route_coords
     return fn
 
 

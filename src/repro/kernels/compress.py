@@ -75,7 +75,34 @@ def fit_block(block: int, d: int, n: int, itemsizes, temps: int = 2) -> int:
     return d if d <= blk else blk
 
 
-# Mosaic parameters of the ``fit_block``-tiled kernels
+# Coordinates of one client row that a leaf-native tile spans at most
+# (``fit_tile``): 2 MiB of f32 per operand at n=4, large enough that the
+# per-grid-step cost vanishes against the DMA.
+LEAF_BLOCK = 1 << 17
+
+
+def fit_tile(R: int, C: int, n: int, itemsizes, plane_itemsizes,
+             temps: int = 6, block: int = LEAF_BLOCK):
+    """The ``(r_blk, c_blk)`` block of an ``(n, r_blk, c_blk)``-tiled
+    kernel over an ``(n, R, C)`` leaf view: at most ``block`` coordinates
+    of a row, and no more than the double-buffered ``(n, ...)`` tiles
+    (one entry of ``itemsizes`` each), the ``(r_blk, c_blk)`` plane
+    operands (``plane_itemsizes``) and ``temps`` f32 plane temporaries
+    fit in ``VMEM_TILE_BYTES``.  ``c_blk`` is ``C`` or a multiple of 128,
+    ``r_blk`` is ``R`` or a multiple of the narrowest dtype's sublane
+    tile (8 rows of f32, 16 of bf16); a ragged last block is a partial
+    grid step."""
+    per = n * 2 * sum(itemsizes) + 2 * sum(plane_itemsizes) + 4 * temps
+    cap = max(8 * 128, min(block, VMEM_TILE_BYTES // per))
+    sub = 32 // min(itemsizes)
+    r_min = min(R, sub)
+    c_blk = C if C * r_min <= cap else max(128, cap // r_min // 128 * 128)
+    r_fit = cap // c_blk
+    r_blk = R if R <= r_fit else max(sub, r_fit // sub * sub)
+    return r_blk, c_blk
+
+
+# Mosaic parameters of the ``fit_block``- and ``fit_tile``-tiled kernels
 TILED_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 

@@ -1,10 +1,25 @@
 """Pallas TPU kernels: the mask-free fused TAMUNA comm step.
 
-Both kernels run over the flat comm workspace (``dist/comm_ws.py``): the
-client-stacked state packed to an ``(n, d)`` f32 buffer, ownership encoded
-by a static per-coordinate ``band`` table and a per-client ``slot`` vector,
-evaluated per VMEM tile via ``compress.owned_from_band`` — no ``(n, d)``
-or ``(d, c)`` mask is ever materialized in HBM.
+Two families, one ownership predicate (``compress.owned_from_band``
+against a per-client ``slot`` vector), evaluated per VMEM tile — no
+``(n, d)`` or ``(d, c)`` mask is ever materialized in HBM:
+
+* ``leaf_masked_sum`` / ``leaf_h_update`` run over ONE state leaf in its
+  own layout, viewed as ``(n, R, C)`` (``leaf_view``), on a 2-D grid of
+  ``(n, r_blk, c_blk)`` tiles (``compress.fit_tile``).  Each tile
+  computes its band from the leaf-local coordinate index ``k = r C + c``
+  (cyclic ``(-s k) mod m``, blocked ``k // ceil(D/m)``), reads the
+  client rows one at a time (slots and DownCom rows from SMEM), and
+  h_update writes h and x in place.  No band table and no packed copy:
+  the shard engine's route for every f32-wire, mean-combined leaf that
+  is not model-sharded (``dist/comm_ws.py``, ``_leaf_routes``).
+* ``masked_sum`` / ``robust_sum`` / ``h_update`` run over the packed
+  comm workspace: the client-stacked leaves of one wire kind packed to
+  an ``(n, d)`` buffer, ownership from a static per-coordinate ``band``
+  table.  They serve the quantized wire, robust combiners and
+  model-sharded leaves, whose layouts the workspace fixes.
+
+The workspace kernels:
 
   masked_sum  UpCom: per-tile ownership, masked client-axis sum, and the
               exact ``1/s`` rebuild fused into one pass — 1 read of x and
@@ -39,21 +54,27 @@ equivalent fused-jnp workspace math.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro import spans
 from repro.kernels.compress import (
+    LEAF_BLOCK,
     TILED_PARAMS,
+    cyclic_band,
     fit_block,
+    fit_tile,
     owned_from_band,
     resolve_interpret,
 )
 
-__all__ = ["masked_sum", "robust_sum", "h_update"]
+__all__ = ["masked_sum", "robust_sum", "h_update", "leaf_view",
+           "leaf_masked_sum", "leaf_h_update"]
 
 
 def _masked_sum_kernel(slot_ref, band_ref, x_ref, o_ref, *, m: int, s: int):
@@ -301,3 +322,197 @@ def h_update(
         name = "h_update"
     return _col_call(kernel, n, d, blk, specs, 0, 2, interpret,
                      name)(*args)
+
+
+# --------------------------------------------------------------------------
+# leaf-native kernels: one state leaf in its own layout, no band table
+# --------------------------------------------------------------------------
+
+
+def leaf_view(trail: Tuple[int, ...]) -> Tuple[int, int]:
+    """``(R, C)`` of a leaf whose client-stacked shape is ``(n, *trail)``:
+    ``C`` the minor dim, ``R`` the product of the dims before it (1 for a
+    1-D parameter).  The reshape to ``(n, R, C)`` is a bitcast on the TPU
+    whenever the second-minor dim is a multiple of the sublane tile."""
+    return int(math.prod(trail[:-1])), int(trail[-1]) if trail else 1
+
+
+def _leaf_band(template: str, m: int, s: int, C: int, D: int,
+               shape: Tuple[int, int]):
+    """The kernel-convention band of the ``shape`` plane tile at grid step
+    ``(i, j)``, from the leaf-local flat index ``k = r * C + c``: the same
+    closed forms as the workspace's band tables (cyclic ``(-s k) mod m``,
+    blocked ``k // ceil(D/m)``), evaluated in VMEM."""
+    r_blk, c_blk = shape
+    rr = (jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+          + pl.program_id(0) * r_blk)
+    cc = (jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+          + pl.program_id(1) * c_blk)
+    k = rr * C + cc
+    if template == "cyclic":
+        return cyclic_band(k, m, s)
+    return jax.lax.div(k, jnp.int32(-(-D // m)))
+
+
+def _row_owned(slot_ref, i, band, m: int, s: int):
+    """Row ``i``'s ownership of the tile: its SMEM slot against the band."""
+    return owned_from_band(jnp.full(band.shape, slot_ref[i], jnp.int32),
+                           band, m, s)
+
+
+def _leaf_masked_sum_kernel(slot_ref, x_ref, *out_refs, n: int, m: int,
+                            s: int, band_of, counts: bool):
+    band = band_of(out_refs[0].shape)
+    zero = jnp.zeros(band.shape, jnp.float32)
+
+    def row(i, acc):
+        num, cnt = acc
+        owned = _row_owned(slot_ref, i, band, m, s)
+        num = num + jnp.where(owned, x_ref[i].astype(jnp.float32), 0.0)
+        if counts:
+            cnt = cnt + owned.astype(jnp.float32)
+        return num, cnt
+
+    num, cnt = jax.lax.fori_loop(0, n, row, (zero, zero),
+                                 unroll=min(n, 8))
+    if counts:
+        out_refs[0][...] = num
+        out_refs[1][...] = cnt
+    else:
+        out_refs[0][...] = num / s
+
+
+def _leaf_h_update_kernel(slot_ref, down_ref, *refs, n: int, m: int, s: int,
+                          scale: float, band_of, covered: bool):
+    if covered:
+        cov_ref, xbar_ref, x_ref, h_ref, h_out, x_out = refs
+    else:
+        xbar_ref, x_ref, h_ref, h_out, x_out = refs
+    x_bar = xbar_ref[...]
+    band = band_of(x_bar.shape)
+    cov = cov_ref[...] != 0 if covered else None
+    bar = x_bar.astype(x_out.dtype)
+
+    def row(i, carry):
+        owned = _row_owned(slot_ref, i, band, m, s)
+        dn = jnp.full(band.shape, down_ref[i], jnp.int32) != 0
+        if cov is not None:
+            owned, dn = owned & cov, dn & cov
+        x = x_ref[i]
+        h_out[i] = (h_ref[i].astype(jnp.float32) + scale * jnp.where(
+            owned, x_bar - x.astype(jnp.float32), 0.0)).astype(h_out.dtype)
+        x_out[i] = jnp.where(dn, bar, x)
+        return carry
+
+    jax.lax.fori_loop(0, n, row, 0, unroll=min(n, 8))
+
+
+def _leaf_call(kernel, shape, tile, in_kinds, outs, interpret, name: str,
+               aliases=None):
+    """``pallas_call`` over a 2-D grid of ``tile`` plane blocks of an
+    ``(n, R, C)`` leaf view of ``shape``.  ``in_kinds`` entries are
+    ``"smem"`` ((n,) int32 whole, in SMEM: read a row at a time),
+    ``"plane"`` ((r_blk, c_blk) block of an (R, C) array) or ``"leaf"``
+    ((n, r_blk, c_blk) block); ``outs`` are ``(kind, dtype)`` pairs of
+    the last two.  Runs under the ``tamuna.uplink`` scope and carries the
+    kernel's ``name``."""
+    n, R, C = shape
+    r_blk, c_blk = tile
+    spec = {
+        "smem": pl.BlockSpec(memory_space=pltpu.SMEM),
+        "plane": pl.BlockSpec((r_blk, c_blk), lambda i, j: (i, j)),
+        "leaf": pl.BlockSpec((n, r_blk, c_blk), lambda i, j: (0, i, j)),
+    }
+    dims = {"plane": (R, C), "leaf": (n, R, C)}
+    call = pl.pallas_call(
+        kernel,
+        grid=(pl.cdiv(R, r_blk), pl.cdiv(C, c_blk)),
+        in_specs=[spec[k] for k in in_kinds],
+        out_specs=tuple(spec[k] for k, _ in outs),
+        out_shape=tuple(jax.ShapeDtypeStruct(dims[k], dt) for k, dt in outs),
+        input_output_aliases=aliases or {},
+        compiler_params=TILED_PARAMS,
+        interpret=resolve_interpret(interpret),
+        name=name,
+    )
+    return jax.named_scope(spans.UPLINK)(call)
+
+
+def _band_of(template: str, m: int, s: int, R: int, C: int):
+    if template not in ("cyclic", "blocked"):
+        raise ValueError(f"unknown template {template!r}")
+    if R * C >= 2**31:
+        raise ValueError(f"leaf of {R * C} coordinates: int32 index")
+    return functools.partial(_leaf_band, template, m, s, C, R * C)
+
+
+def leaf_masked_sum(
+    x: jax.Array,  # (n, R, C) leaf view (``leaf_view``), any float dtype
+    slot: jax.Array,  # (n,) int32; outside [0, m) -> contributes nothing
+    m: int,
+    s: int,
+    *,
+    template: str,  # "cyclic" | "blocked"
+    counts: bool = False,
+    block: int = LEAF_BLOCK,
+    interpret: Optional[bool] = None,
+):
+    """``masked_sum`` over one leaf in its own layout: ownership from the
+    leaf-local coordinate index inside the kernel (no band table), the
+    client-axis sum and the ``1/s`` rebuild in f32.  Returns the ``(R,
+    C)`` x_bar, or with ``counts=True`` the raw ``(num, cnt)`` pair (see
+    ``masked_sum``)."""
+    n, R, C = x.shape
+    n_out = 2 if counts else 1
+    tile = fit_tile(R, C, n, [x.dtype.itemsize], [4] * n_out, block=block)
+    kernel = functools.partial(
+        _leaf_masked_sum_kernel, n=n, m=m, s=s,
+        band_of=_band_of(template, m, s, R, C), counts=counts)
+    out = _leaf_call(kernel, x.shape, tile, ["smem", "leaf"],
+                     [("plane", jnp.float32)] * n_out, interpret,
+                     "leaf_masked_sum_counts" if counts
+                     else "leaf_masked_sum")(slot, x)
+    return tuple(out) if counts else out[0]
+
+
+def leaf_h_update(
+    x: jax.Array,  # (n, R, C) leaf view
+    h: jax.Array,  # (n, R, C) control variates
+    x_bar: jax.Array,  # (R, C) f32 rebuilt server model
+    slot: jax.Array,  # (n,) int32
+    m: int,
+    s: int,
+    scale: float,  # eta / gamma
+    *,
+    template: str,
+    down: Optional[jax.Array] = None,  # (n,) int32/bool DownCom targets
+    covered: Optional[jax.Array] = None,  # (R, C) bool: coord has a survivor
+    block: int = LEAF_BLOCK,
+    interpret: Optional[bool] = None,
+) -> Tuple[jax.Array, jax.Array]:
+    """``h_update`` over one leaf in its own layout, h and x updated in
+    place (x aliases x_new, h aliases h_new): ``h += scale * owned *
+    (x_bar - x)`` in f32 and the DownCom ``x_new = x_bar`` on the
+    ``down`` rows, in each leaf's storage dtype.  ``covered`` gates both
+    per coordinate as in ``h_update``."""
+    n, R, C = x.shape
+    planes = [4] * (2 if covered is not None else 1)
+    tile = fit_tile(R, C, n, [x.dtype.itemsize, h.dtype.itemsize] * 2,
+                    planes, block=block)
+    down = (jnp.ones((n,), jnp.int32) if down is None
+            else down.astype(jnp.int32))
+    args = [slot, down]
+    if covered is not None:
+        args.append(covered.astype(jnp.int32))
+    args += [x_bar, x, h]
+    kernel = functools.partial(
+        _leaf_h_update_kernel, n=n, m=m, s=s, scale=scale,
+        band_of=_band_of(template, m, s, R, C),
+        covered=covered is not None)
+    kinds = ["smem", "smem"] + ["plane"] * len(planes) + ["leaf", "leaf"]
+    ix = len(args) - 2  # x, then h: updated in place
+    return _leaf_call(
+        kernel, x.shape, tile, kinds,
+        [("leaf", h.dtype), ("leaf", x.dtype)], interpret,
+        "leaf_h_update_covered" if covered is not None else "leaf_h_update",
+        aliases={ix: 1, ix + 1: 0})(*args)

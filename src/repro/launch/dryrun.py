@@ -213,6 +213,10 @@ def run_one(
                 wire_summary(arch, shape_name, tcfg)
                 if step_name in ("comm", "round") else None
             ),
+            # coordinates of a client row per comm route (leaf-native
+            # kernels, packed workspace, per-leaf jnp; DESIGN.md §10), as
+            # the comm step lowered here dispatches them
+            "routes": getattr(b.fn, "route_coords", None),
             # robust combiner over the s owner values (DESIGN.md §15):
             # mean / trimmed-k / median; mean (and trimmed k=0) lowers
             # the bitwise legacy aggregation

@@ -21,6 +21,8 @@ Single-device hypothesis sweeps of the same engine live in
 tests/test_comm_ws.py (1x1 mesh).
 """
 
+import pytest
+
 
 def test_shard_engine_matches_dense_across_meshes(subproc):
     subproc("""
@@ -166,7 +168,11 @@ for uplink in ("masked_psum", "block_rs"):
                       tamuna_dp.state_pspecs(state, cfg, mesh),
                       is_leaf=lambda x: isinstance(x, P))
     state = jax.device_put(state, sh)
-    fn = jax.jit(tamuna_dp.make_comm_step(cfg, tcfg, mesh))
+    step = tamuna_dp.make_comm_step(cfg, tcfg, mesh)
+    # off the TPU the per-shard kernels are off: every leaf on jnp
+    assert step.route_coords == {"leaf": 0, "workspace": 0,
+                                 "jnp": d_total}, step.route_coords
+    fn = jax.jit(step)
     hlo = fn.lower(state, jax.random.key(0)).compile().as_text()
     worst = max_coll_elems(hlo)
     # d-sized collectives only: the engine's psum of the concatenated
@@ -192,3 +198,113 @@ worst = max_coll_elems(bad.lower(xs, hs).compile().as_text())
 assert worst >= 2 * D, worst  # s * D with s=2
 print("OK")
 """, devices=8)
+
+
+@pytest.mark.parametrize("shape", [(1, 1), (4, 2)])
+def test_shard_engine_leaf_route_matches_dense(subproc, shape):
+    """Leaves of ``LEAF_MIN`` coordinates a row or more take the
+    leaf-native kernels (interpret mode here) in their own layouts: 2-D,
+    3-D and 4-D stacked leaves with C not a multiple of 128, beside a
+    small leaf on the jnp route; both templates, all rows, c < n with a
+    DownCom mask, and the survivor rebuild under dropped owners — all to
+    <= 1e-6 of dense, on one shard and on 4 client shards."""
+    subproc(f"""
+import numpy as np, jax, jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.dist import comm_ws
+
+mesh = jax.make_mesh({shape!r}, ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
+n, c, s = 8, 5, 2
+rng = np.random.default_rng(7)
+trails = {{"a": (257, 260), "b": (2, 33, 1000), "v": (70001,), "t": (29,)}}
+x = {{k: jnp.asarray(rng.normal(size=(n,) + t), jnp.float32)
+     for k, t in trails.items()}}
+h = {{k: jnp.asarray(rng.normal(size=a.shape), jnp.float32)
+     for k, a in x.items()}}
+sh = NamedSharding(mesh, P("data"))
+xs, hs = jax.device_put(x, sh), jax.device_put(h, sh)
+big = sum(int(np.prod(t)) for t in trails.values()
+          if np.prod(t) >= comm_ws.LEAF_MIN)
+for tmpl in ("cyclic", "blocked"):
+    got = comm_ws.route_coords(x, tmpl, c, s, impl="pallas", mesh=mesh,
+                               shard_kernels=True)
+    assert got == {{"leaf": big, "workspace": 0, "jnp": 29}}, got
+
+def maxerr(a, b):
+    return max(
+        float(jnp.abs(u.astype(jnp.float32) - v.astype(jnp.float32)).max())
+        for u, v in zip(jax.tree.leaves(a), jax.tree.leaves(b)))
+
+sl = np.full((n,), -1, np.int32)
+sl[rng.choice(n, size=c, replace=False)] = rng.permutation(c)
+slot = jnp.asarray(sl)
+down = jnp.asarray(rng.integers(0, 2, size=n).astype(bool))
+arrived = jnp.asarray((sl != 0) & (sl != 1))  # slots 0, 1: owners dropped
+full = jnp.asarray(np.roll(np.arange(n, dtype=np.int32), 3))
+off = jnp.asarray(2, jnp.int32)
+cases = [
+    dict(c=n, slot=full),
+    dict(c=c, slot=slot, down=down),
+    dict(c=c, slot=slot, down=down, arrived=arrived),
+]
+for kw in cases:
+    m, sl_, kw = kw.pop("c"), kw.pop("slot"), kw
+    xd, hd = comm_ws.cyclic_comm(x, h, sl_, m, s, 0.37, impl="dense", **kw)
+    xn, hn = jax.jit(lambda xs, hs: comm_ws.cyclic_comm(
+        xs, hs, sl_, m, s, 0.37, impl="pallas", meshed=True, mesh=mesh,
+        shard_kernels=True, **kw))(xs, hs)
+    assert maxerr(xd, xn) <= 1e-6 and maxerr(hd, hn) <= 1e-6, ("cyc", m)
+    bkw = dict(kw, c=m, slot_of=sl_)
+    bd = comm_ws.blocked_comm(x, h, off, n, s, 0.37, impl="dense", **bkw)
+    xb, hb = jax.jit(lambda xs, hs: comm_ws.blocked_comm(
+        xs, hs, off, n, s, 0.37, impl="pallas", meshed=True, mesh=mesh,
+        shard_kernels=True, **bkw))(xs, hs)
+    assert maxerr(bd[0], xb) <= 1e-6 and maxerr(bd[1], hb) <= 1e-6, \\
+        ("blk", m)
+print("OK")
+""", devices=8)
+
+
+@pytest.mark.parametrize("template", ["cyclic", "blocked"])
+@pytest.mark.parametrize("wire", ["f32", "int8"])
+def test_meshed_f32_comm_holds_no_workspace(template, wire):
+    """HLO regression: with the kernels on, the meshed f32 comm program
+    packs nothing — no concatenate into an ``(n, d_total)`` buffer and no
+    int32 band constant of a leaf's size.  The quantized wire, which
+    keeps the workspace for its per-chunk scales, is the positive
+    control: it still concatenates the leaves and bakes their bands."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.dist import comm_ws
+
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    n = 4
+    x = {"a": jnp.zeros((n, 257, 260)), "v": jnp.zeros((n, 70001)),
+         "t": jnp.zeros((n, 29))}
+    h = jax.tree.map(jnp.zeros_like, x)
+    dims = [int(np.prod(a.shape[1:])) for a in jax.tree.leaves(x)]
+    kw = dict(impl="pallas", meshed=True, mesh=mesh, shard_kernels=True,
+              wire=wire, wire_seed=jnp.uint32(3))
+    if template == "cyclic":
+        slot = jnp.arange(n, dtype=jnp.int32)
+        fn = lambda x, h: comm_ws.cyclic_comm(x, h, slot, n, 2, 0.5, **kw)
+    else:
+        fn = lambda x, h: comm_ws.blocked_comm(x, h, jnp.int32(1), n, 2,
+                                               0.5, **kw)
+    hlo = jax.jit(fn).lower(x, h).as_text(dialect="hlo")
+    consts = {int(d) for d in re.findall(r"s32\[(\d+)\]\{0\} constant\(",
+                                         hlo)}
+    packed = {int(d) for _, d in re.findall(
+        r"f32\[(\d+),(\d+)\]\{1,0\} concatenate\(", hlo)}
+    if wire == "f32":
+        assert not consts & set(dims), consts
+        assert sum(dims) not in packed, packed
+    else:
+        assert max(dims) in consts, consts
+        assert sum(dims) in packed, packed

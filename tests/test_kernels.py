@@ -246,6 +246,98 @@ def test_fit_block_is_a_whole_vector_tile_within_vmem(n, d, itemsizes):
 
 
 # --------------------------------------------------------------------------
+# leaf-native uplink kernels: one state leaf in its own (n, R, C) view,
+# ownership from the leaf-local coordinate index (no band table)
+# --------------------------------------------------------------------------
+
+
+def _closed_form_band(template, D, m, s):
+    from repro.dist import comm_ws
+
+    if template == "cyclic":
+        return jnp.asarray(comm_ws._cyclic_band_np((D,), m, s))
+    return jnp.asarray(comm_ws._block_leaf_band_np(D, m))
+
+
+@pytest.mark.parametrize("template", ["cyclic", "blocked"])
+@pytest.mark.parametrize("trail", [
+    (3000,),       # a 1-D parameter: (n, 1, C), C ragged against c_blk
+    (37, 300),     # R ragged against r_blk, C not a multiple of 128
+    (3, 20, 200),  # 4-D stacked leaf folded to (n, 60, 200)
+])
+@pytest.mark.parametrize("variant", ["all_rows", "down", "survivor"])
+def test_leaf_uplink_matches_ref(template, trail, variant):
+    """The leaf kernels over a small ``block`` (a multi-step 2-D grid with
+    ragged last blocks) against the ref.py oracles fed the closed-form
+    band of the flattened leaf: c < n (idle rows), the DownCom row mask,
+    and the survivor ``counts`` / ``covered`` variants with dropped
+    owners."""
+    n, m, s = 6, 5, 2
+    rng = np.random.default_rng(len(trail) * 7 + len(variant))
+    x = jnp.asarray(rng.normal(size=(n,) + trail), jnp.float32)
+    h = jnp.asarray(rng.normal(size=(n,) + trail), jnp.float32)
+    slot = np.full((n,), -1, np.int32)
+    slot[rng.choice(n, size=m, replace=False)] = rng.permutation(m)
+    if variant == "survivor":
+        # drop the rows holding slots 0 and 1: both templates give every
+        # coordinate s=2 owners at consecutive slots, so some lose both
+        slot[(slot == 0) | (slot == 1)] = -1
+    slot = jnp.asarray(slot)
+    R, C = uplink.leaf_view(trail)
+    D = R * C
+    x3, h3 = x.reshape(n, R, C), h.reshape(n, R, C)
+    xf, hf = x.reshape(n, D), h.reshape(n, D)
+    band = _closed_form_band(template, D, m, s)
+    kw = dict(template=template, block=1024, interpret=True)
+    down = cov = None
+    if variant == "survivor":
+        num, cnt = uplink.leaf_masked_sum(x3, slot, m, s, counts=True, **kw)
+        num_e, cnt_e = ref.uplink_masked_sum_ref(xf, slot, band, m, s,
+                                                 counts=True)
+        np.testing.assert_array_equal(np.asarray(cnt).reshape(-1),
+                                      np.asarray(cnt_e))
+        np.testing.assert_allclose(np.asarray(num).reshape(-1),
+                                   np.asarray(num_e), rtol=1e-6, atol=1e-6)
+        x_bar, cov = num / jnp.maximum(cnt, 1.0), cnt > 0
+        assert not bool(cov.all())  # the dropped owners uncover some
+    else:
+        x_bar = uplink.leaf_masked_sum(x3, slot, m, s, **kw)
+        np.testing.assert_allclose(
+            np.asarray(x_bar).reshape(-1),
+            np.asarray(ref.uplink_masked_sum_ref(xf, slot, band, m, s)),
+            rtol=1e-6, atol=1e-6)
+    if variant != "all_rows":
+        down = jnp.asarray(rng.integers(0, 2, size=n).astype(np.int32))
+    h_new, x_new = uplink.leaf_h_update(x3, h3, x_bar, slot, m, s, 0.25,
+                                        down=down, covered=cov, **kw)
+    h_exp, x_exp = ref.uplink_h_update_ref(
+        xf, hf, x_bar.reshape(-1), slot, band, m, s, 0.25, down=down,
+        covered=None if cov is None else cov.reshape(-1))
+    assert h_new.shape == x_new.shape == (n, R, C)
+    np.testing.assert_allclose(np.asarray(h_new).reshape(n, D),
+                               np.asarray(h_exp), rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(x_new).reshape(n, D),
+                                  np.asarray(x_exp))
+
+
+@pytest.mark.parametrize("n,R,C,itemsizes", [
+    (4, 2560, 6912, [4, 4, 4, 4]),   # stablelm's MLP leaves, h_update
+    (8, 51968, 384, [4]),            # whisper's padded embedding, masked_sum
+    (8, 1, 19_955_712, [2, 4] * 2),  # a 1-D parameter, bf16 x
+    (512, 1536, 384, [4, 4, 4, 4]),  # many rows a shard
+])
+def test_fit_tile_is_tiled_and_within_vmem(n, R, C, itemsizes):
+    r_blk, c_blk = compress.fit_tile(R, C, n, itemsizes, [4])
+    sub = 32 // min(itemsizes)
+    assert c_blk == C or (c_blk % 128 == 0 and c_blk < C)
+    assert r_blk == R or (r_blk % sub == 0 and r_blk < R)
+    per = n * 2 * sum(itemsizes) + 2 * 4 + 4 * 6
+    assert r_blk * c_blk <= compress.LEAF_BLOCK or r_blk * c_blk <= 8 * 128
+    assert (r_blk * c_blk * per <= compress.VMEM_TILE_BYTES
+            or (r_blk <= sub and c_blk == 128))
+
+
+# --------------------------------------------------------------------------
 # fused local step
 # --------------------------------------------------------------------------
 

@@ -135,6 +135,51 @@ def test_kernel_at_n512_fits_scoped_vmem(one_chip, kernel):
     assert "tpu_custom_call" in hlo
 
 
+# leaf views (n, R, C) the leaf-native kernels see at the cells' widths:
+# stablelm's largest leaf, whisper's MLP leaves and its padded embedding
+LEAF_VIEWS = {"stablelm_mlp": (4, 2560, 6912), "whisper_mlp": (8, 1536, 1536),
+              "whisper_embed": (8, 51968, 384)}
+
+
+def _leaf_case(kernel, n, R, C):
+    """(fn, shapes, donate) of one leaf kernel over an ``(n, R, C)`` view;
+    h_update's donated x and h come back first, as they are aliased."""
+    f32, i32 = jnp.float32, jnp.int32
+    leaf, plane, row = ((n, R, C), f32), ((R, C), f32), ((n,), i32)
+    tmpl = "blocked" if n == 4 else "cyclic"
+    m, s = min(n, M), 2
+    if kernel.startswith("leaf_masked_sum"):
+        counts = kernel.endswith("counts")
+        return (lambda x, sl: uplink.leaf_masked_sum(
+            x, sl, m, s, template=tmpl, counts=counts, interpret=False),
+            [leaf, row], ())
+    if kernel == "leaf_h_update":
+        return (lambda x, h, xb, sl, dn: uplink.leaf_h_update(
+            x, h, xb, sl, m, s, 0.5, template=tmpl, down=dn,
+            interpret=False)[::-1], [leaf, leaf, plane, row, row], (0, 1))
+    return (lambda x, h, xb, sl, dn, cv: uplink.leaf_h_update(
+        x, h, xb, sl, m, s, 0.5, template=tmpl, down=dn, covered=cv,
+        interpret=False)[::-1],
+        [leaf, leaf, plane, row, row, ((R, C), jnp.bool_)], (0, 1))
+
+
+@pytest.mark.parametrize("view", sorted(LEAF_VIEWS))
+@pytest.mark.parametrize("kernel", [
+    "leaf_masked_sum", "leaf_masked_sum_counts", "leaf_h_update",
+    "leaf_h_update_covered"])
+def test_leaf_kernel_compiles_in_place_at_cell_width(one_chip, kernel, view):
+    """Mosaic takes the leaf-native kernels at the cells' leaf views, and
+    h_update updates the donated x and h in place: the program's
+    temporaries stay below one leaf (no copy of x or h)."""
+    n, R, C = LEAF_VIEWS[view]
+    fn, shapes, donate = _leaf_case(kernel, n, R, C)
+    args = [jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
+            for sh, dt in shapes]
+    comp = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+    assert "tpu_custom_call" in comp.as_text()
+    assert comp.memory_analysis().temp_size_in_bytes < n * R * C * 4
+
+
 def _shard_engine_hlo(topo, shape, monkeypatch):
     """The §10 shard engine's cyclic comm step at the whisper-tiny width,
     compiled for a ``shape`` (data, model) mesh of the described chips,
